@@ -82,7 +82,6 @@ from .snapshot.lazy import (
 from .snapshot.ring import SnapshotRing, rollback_many
 from .utils import compile_guard
 from .utils.frames import NULL_FRAME, frame_add
-from .utils.tracing import span
 
 
 class _Op:
@@ -525,7 +524,7 @@ class BatchedRunner:
         for h, v in self.read_inputs(b, handles).items():
             s.add_local_input(h, v)
         try:
-            with self._phases.phase("session_step"), span("SessionAdvanceFrame"):
+            with self._phases.phase("session_step"):
                 requests = s.advance_frame()
         except MismatchedChecksumError as e:
             self._report_mismatch(b, e)
@@ -627,7 +626,7 @@ class BatchedRunner:
                     hits[b] = (f, got, nxt)
         if hits:
             t_hit = time.perf_counter()
-            with self._phases.phase("rollback_load"), span("LoadWorldBatched"):
+            with self._phases.phase("rollback_load"):
                 for b, (f, got, nxt) in hits.items():
                     d, states_fn, checks_b = got
                     # bookkeeping-only rollback: pop the newer ring entries,
@@ -673,7 +672,7 @@ class BatchedRunner:
             if not loads:
                 return
         t_miss = time.perf_counter()
-        with self._phases.phase("rollback_load"), span("LoadWorldBatched"):
+        with self._phases.phase("rollback_load"):
             # batched mixed-source load: roll every ring back, group the
             # stored LazySlice handles by backing stacked buffer, and serve
             # the whole wave — even when lobbies load from DIFFERENT past
@@ -802,7 +801,7 @@ class BatchedRunner:
             if self.planner is not None:
                 self.planner.plan(ks)
                 wave_ks = ks + [0] * (self._m_pad - m)
-            with ph.phase("wave_dispatch"), span("AdvanceWorldBatched"):
+            with ph.phase("wave_dispatch"):
                 if self.packed:
                     bucket, finals, stacked, checks_flat = (
                         self.exec.run_wave_packed(
@@ -829,7 +828,7 @@ class BatchedRunner:
                 # state (the one feeding its LAST advance)
                 self._last_wave = (prev_worlds, stacked, list(ks))
                 self._last_adv = adv
-        with ph.phase("store_save"), span("SaveWorldBatched"):
+        with ph.phase("store_save"):
             # collect this wave's saves as (lobby, advance-count-before, req)
             saves = []
             for b, run in enumerate(runs):
@@ -935,7 +934,7 @@ class BatchedRunner:
                 else LazySlice(prev_worlds, b)
             )
             rows.append((lane, src))
-        with self._phases.phase("wave_dispatch"), span("DraftWaveBatched"):
+        with self._phases.phase("wave_dispatch"):
             groups, fallback = plan_row_gather(rows)
             draft_worlds = self.worlds
             if groups:

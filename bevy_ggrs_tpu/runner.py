@@ -45,7 +45,7 @@ from .ops.resim import slice_frame
 from .ops.speculation import SpeculationCache, SpeculationConfig
 from .utils import compile_guard
 from .utils.frames import NULL_FRAME, frame_add
-from .utils.tracing import span, trace_log
+from .utils.tracing import trace_log
 
 
 class GgrsRunner:
@@ -465,8 +465,7 @@ class GgrsRunner:
                 self._rbq.harvest()
         if hasattr(self.session, "poll_remote_clients"):
             with ph.phase("net_poll"):
-                with span("PollRemoteClients"):
-                    self.session.poll_remote_clients()
+                self.session.poll_remote_clients()
                 self._drain_events()
                 if self._netstats is not None:
                     self._netstats.poll()
@@ -539,7 +538,8 @@ class GgrsRunner:
             # synchronous mode: retire checksum readbacks with the tick —
             # these count as forced (blocking) pulls in the readback stats
             BatchChecks.pull_pending()
-        jax.block_until_ready(self._world.comps)
+        with self._phases.phase("readback_block"):
+            jax.block_until_ready(self._world.comps)
 
     def read_components(self, names=None) -> dict:
         """Fetch component columns (and the active mask) to host numpy in one
@@ -638,8 +638,7 @@ class GgrsRunner:
         for handle, value in self.read_inputs(self.local_players).items():
             s.add_local_input(handle, value)
         try:
-            with span("SessionAdvanceFrame"):
-                return s.advance_frame()
+            return s.advance_frame()
         except MismatchedChecksumError as e:
             trace_log("SyncTest mismatch: %s", e)
             self._report_mismatch(e)
@@ -654,8 +653,7 @@ class GgrsRunner:
             for handle, value in self.read_inputs(self.local_players).items():
                 s.add_local_input(handle, value)
         try:
-            with span("SessionAdvanceFrame"):
-                requests = s.advance_frame()
+            requests = s.advance_frame()
         except PredictionThresholdError:
             trace_log("frame %d skipped: prediction threshold", self.frame)
             self.stalled_frames += 1
@@ -775,66 +773,65 @@ class GgrsRunner:
     # -- request dispatch (the TPU-offload seam, SURVEY §3.6) ---------------
 
     def _handle_requests(self, requests: List[GgrsRequest]) -> None:
-        with span("HandleRequests"):
-            s = self.session
-            # mirror session -> driver counters (schedule_systems.rs:195-220)
-            self.ring.set_depth(self._ring_depth(s))
-            self.confirmed = s.confirmed_frame()
-            i = 0
-            n = len(requests)
-            while i < n:
-                r = requests[i]
-                if isinstance(r, LoadRequest):
-                    if self.megastep:
-                        # fuse the load into the following run's megastep
-                        # dispatch when its target is device-ring resident
-                        j = i + 1
-                        while j < n and isinstance(
-                            requests[j], (AdvanceRequest, SaveRequest)
-                        ):
-                            j += 1
-                        self._run_megastep(r, requests[i + 1:j])
-                        i = j
-                    else:
-                        # rollback servicing seam: the Load plus its
-                        # following Advance/Save run are one unit — a
-                        # verified speculation hit replaces BOTH the ring
-                        # materialize and the resim with cache selects
-                        j = i + 1
-                        while j < n and isinstance(
-                            requests[j], (AdvanceRequest, SaveRequest)
-                        ):
-                            j += 1
-                        self._service_rollback(r, requests[i + 1:j])
-                        i = j
-                else:
-                    j = i
+        s = self.session
+        # mirror session -> driver counters (schedule_systems.rs:195-220)
+        self.ring.set_depth(self._ring_depth(s))
+        self.confirmed = s.confirmed_frame()
+        i = 0
+        n = len(requests)
+        while i < n:
+            r = requests[i]
+            if isinstance(r, LoadRequest):
+                if self.megastep:
+                    # fuse the load into the following run's megastep
+                    # dispatch when its target is device-ring resident
+                    j = i + 1
                     while j < n and isinstance(
                         requests[j], (AdvanceRequest, SaveRequest)
                     ):
                         j += 1
-                    if self.megastep:
-                        self._run_megastep(None, requests[i:j])
-                    else:
-                        self._run_batch(requests[i:j])
+                    self._run_megastep(r, requests[i + 1:j])
                     i = j
-            # prune AFTER processing (discard_old_snapshots): with coalesced
-            # ticks, an early tick's Load can target a frame below a LATER
-            # tick's confirmed frame (the session takes first_incorrect per
-            # tick, then lets confirmed rise) — pruning up front would evict
-            # the rollback target, the exact MissingSnapshotError shape of
-            # the round-4 donation regression
-            self.ring.confirm(self.confirmed)
-            # fire AFTER the batch: a corrective Load/Advance in the same
-            # request list must land before observers treat the frame as
-            # final (a replay watermark reading final_frames() from this
-            # hook would otherwise persist the mispredicted inputs)
-            if self.on_confirmed is not None and self.confirmed != NULL_FRAME:
-                self.on_confirmed(self.confirmed)
-            # drafts for the live frame ride the idle post-tick slot: the
-            # fan-out dispatch + cache bookkeeping happen after every
-            # rollback in this list has been serviced (and timed)
-            self._flush_speculation()
+                else:
+                    # rollback servicing seam: the Load plus its
+                    # following Advance/Save run are one unit — a
+                    # verified speculation hit replaces BOTH the ring
+                    # materialize and the resim with cache selects
+                    j = i + 1
+                    while j < n and isinstance(
+                        requests[j], (AdvanceRequest, SaveRequest)
+                    ):
+                        j += 1
+                    self._service_rollback(r, requests[i + 1:j])
+                    i = j
+            else:
+                j = i
+                while j < n and isinstance(
+                    requests[j], (AdvanceRequest, SaveRequest)
+                ):
+                    j += 1
+                if self.megastep:
+                    self._run_megastep(None, requests[i:j])
+                else:
+                    self._run_batch(requests[i:j])
+                i = j
+        # prune AFTER processing (discard_old_snapshots): with coalesced
+        # ticks, an early tick's Load can target a frame below a LATER
+        # tick's confirmed frame (the session takes first_incorrect per
+        # tick, then lets confirmed rise) — pruning up front would evict
+        # the rollback target (a MissingSnapshotError once a donated
+        # world is gone)
+        self.ring.confirm(self.confirmed)
+        # fire AFTER the batch: a corrective Load/Advance in the same
+        # request list must land before observers treat the frame as
+        # final (a replay watermark reading final_frames() from this
+        # hook would otherwise persist the mispredicted inputs)
+        if self.on_confirmed is not None and self.confirmed != NULL_FRAME:
+            self.on_confirmed(self.confirmed)
+        # drafts for the live frame ride the idle post-tick slot: the
+        # fan-out dispatch + cache bookkeeping happen after every
+        # rollback in this list has been serviced (and timed)
+        self._flush_speculation()
 
     def _flush_speculation(self) -> None:
         """Apply the cache-maintenance ops recorded during request handling.
@@ -922,7 +919,7 @@ class GgrsRunner:
         handle ``"unknown"`` so ``rollback_cause_total`` summed over handles
         always equals ``rollbacks_total``."""
         self._note_rollback(frame, cause)
-        with self._phases.phase("rollback_load"), span("LoadWorld"):
+        with self._phases.phase("rollback_load"):
             stored, checksum = self.ring.rollback(frame)
             was_lazy = isinstance(stored, LazySlice)
             if (
@@ -999,7 +996,7 @@ class GgrsRunner:
             )
         if got is not None:
             self._note_rollback(load.frame, load.cause)
-            with self._phases.phase("rollback_load"), span("LoadWorld"):
+            with self._phases.phase("rollback_load"):
                 # bookkeeping-only rollback: pop the ring entries above the
                 # target and keep the stored handle — no materialize, no
                 # load_state; the world restore is the cache select inside
@@ -1251,79 +1248,78 @@ class GgrsRunner:
                 telemetry.count(
                     "donated_dispatches_total", help="dispatches donating the input world"
                 )
-            with span("AdvanceWorld"):
-                pk = None
-                if use_packed:
-                    # fixed-shape canonical programs take a canonical_depth-
-                    # deep buffer with the real count in the prefix; the
-                    # per-k programs take exactly [k+1, W]
-                    K = self.app.canonical_depth
-                    if K is not None and k - skip > K:
-                        raise ValueError(
-                            f"resim depth {k - skip} exceeds canonical_depth "
-                            f"{K}; raise App(canonical_depth=...) above "
-                            "every session window"
-                        )
-                    with ph.phase("stage_inputs"):
-                        pk = self._stage_packed_rows(
-                            adv[skip:], self.frame, k_pad=K
-                        )
-                else:
-                    with ph.phase("stage_inputs"):
-                        inputs, status = self._stage_rows(adv[skip:])
-                variant = (
-                    "branched" if use_branched
-                    else (("packed_" if use_packed else "")
-                          + ("donated" if donate else "plain")),
-                    k - skip,
-                )
-                fresh = variant not in self._seen_variants
-                t_build = time.perf_counter() if fresh else 0.0
-                with ph.phase("wave_dispatch"):
-                    if use_branched:
-                        final, stacked, checks = self._dispatch_branched(
-                            inputs, status, adv[-1]
-                        )
-                        self._note_dispatch_uploads(4)
-                    elif use_packed:
-                        fn = (
-                            self.app.packed_resim_fn_donated if donate
-                            else self.app.packed_resim_fn
-                        )
-                        if donate:
-                            self.donated_dispatches += 1
-                        final, stacked, checks = fn(self.world, pk)
-                        self._note_dispatch_uploads(1, pk)
-                    else:
-                        fn = (
-                            self.app.resim_fn_donated if donate
-                            else self.app.resim_fn
-                        )
-                        if donate:
-                            self.donated_dispatches += 1
-                        final, stacked, checks = fn(
-                            self.world, inputs, status, self.frame
-                        )
-                        self._note_dispatch_uploads(3)
-                    batch_checks = BatchChecks(checks)
-                    if self.pipeline:
-                        # ahead-of-tick readback: the device->host checksum
-                        # copy rides behind the dispatch; harvest() collects
-                        # it next tick while the device runs frame N+1
-                        self._rbq.start(batch_checks)
-                if fresh:
-                    self._note_compile(variant, time.perf_counter() - t_build)
-                if self.spec_cache is not None and k - skip >= 2:
-                    last_adv_src = (
-                        lambda s=stacked, i=k - skip - 2: slice_frame(s, i)
+            pk = None
+            if use_packed:
+                # fixed-shape canonical programs take a canonical_depth-
+                # deep buffer with the real count in the prefix; the
+                # per-k programs take exactly [k+1, W]
+                K = self.app.canonical_depth
+                if K is not None and k - skip > K:
+                    raise ValueError(
+                        f"resim depth {k - skip} exceeds canonical_depth "
+                        f"{K}; raise App(canonical_depth=...) above "
+                        "every session window"
                     )
-                self.world = final
-                self._world_checksum = batch_checks.ref(k - skip - 1)
-                self.frame = frame_add(self.frame, k - skip)
-                self._last_stacked = stacked
-                self._last_k = k - skip
-                self._last_stacked_frame = self.frame
-                self._world_donatable = True  # final is a fresh buffer
+                with ph.phase("stage_inputs"):
+                    pk = self._stage_packed_rows(
+                        adv[skip:], self.frame, k_pad=K
+                    )
+            else:
+                with ph.phase("stage_inputs"):
+                    inputs, status = self._stage_rows(adv[skip:])
+            variant = (
+                "branched" if use_branched
+                else (("packed_" if use_packed else "")
+                      + ("donated" if donate else "plain")),
+                k - skip,
+            )
+            fresh = variant not in self._seen_variants
+            t_build = time.perf_counter() if fresh else 0.0
+            with ph.phase("wave_dispatch"):
+                if use_branched:
+                    final, stacked, checks = self._dispatch_branched(
+                        inputs, status, adv[-1]
+                    )
+                    self._note_dispatch_uploads(4)
+                elif use_packed:
+                    fn = (
+                        self.app.packed_resim_fn_donated if donate
+                        else self.app.packed_resim_fn
+                    )
+                    if donate:
+                        self.donated_dispatches += 1
+                    final, stacked, checks = fn(self.world, pk)
+                    self._note_dispatch_uploads(1, pk)
+                else:
+                    fn = (
+                        self.app.resim_fn_donated if donate
+                        else self.app.resim_fn
+                    )
+                    if donate:
+                        self.donated_dispatches += 1
+                    final, stacked, checks = fn(
+                        self.world, inputs, status, self.frame
+                    )
+                    self._note_dispatch_uploads(3)
+                batch_checks = BatchChecks(checks)
+                if self.pipeline:
+                    # ahead-of-tick readback: the device->host checksum
+                    # copy rides behind the dispatch; harvest() collects
+                    # it next tick while the device runs frame N+1
+                    self._rbq.start(batch_checks)
+            if fresh:
+                self._note_compile(variant, time.perf_counter() - t_build)
+            if self.spec_cache is not None and k - skip >= 2:
+                last_adv_src = (
+                    lambda s=stacked, i=k - skip - 2: slice_frame(s, i)
+                )
+            self.world = final
+            self._world_checksum = batch_checks.ref(k - skip - 1)
+            self.frame = frame_add(self.frame, k - skip)
+            self._last_stacked = stacked
+            self._last_k = k - skip
+            self._last_stacked_frame = self.frame
+            self._world_donatable = True  # final is a fresh buffer
         materialize_saves = False
         if stacked is not None:
             stacked_bytes = self._stacked_bytes_by_k.get(k - skip)
@@ -1342,7 +1338,7 @@ class GgrsRunner:
                 donated=donate, save_bytes=stacked_bytes,
             )
         pushed_pre_world = False
-        with ph.phase("store_save"), span("SaveWorld"):
+        with ph.phase("store_save"):
             c = 0  # advances seen so far within the run
             for r in run:
                 if isinstance(r, AdvanceRequest):
@@ -1500,7 +1496,7 @@ class GgrsRunner:
                 self._load(load.frame, load.cause)
             else:
                 self._note_rollback(load.frame, load.cause)
-                with ph.phase("rollback_load"), span("LoadWorld"):
+                with ph.phase("rollback_load"):
                     # bookkeeping only: pop newer host-ring entries and take
                     # the checksum handle; the STATE restore happens inside
                     # the megastep dispatch (no materialize, no extra
@@ -1565,34 +1561,33 @@ class GgrsRunner:
                 "resim_frames_total", max(k - 1, 0),
                 help="frames resimulated beyond the first of each dispatch",
             )
-            with span("AdvanceWorld"):
-                with ph.phase("stage_inputs"):
-                    pk = self._stage_packed_rows(
-                        adv, self.frame, k_pad=self._ms_k,
-                        has_load=has_load, load_slot=load_slot,
-                    )
-                variant = ("megastep", self._ms_k)
-                fresh = variant not in self._seen_variants
-                t_build = time.perf_counter() if fresh else 0.0
-                with ph.phase("wave_dispatch"):
-                    (final, self._ms_ring, self._ms_ring_frames, stacked,
-                     checks) = self._ms_fn(
-                        self.world, self._ms_ring, self._ms_ring_frames, pk
-                    )
-                    self._note_dispatch_uploads(1, pk)
-                    batch_checks = BatchChecks(checks)
-                    if self.pipeline:
-                        self._rbq.start(batch_checks)
-                if fresh:
-                    self._note_compile(variant, time.perf_counter() - t_build)
-                # host mirror of the device ring writeback (slot -> frame)
-                R = self._ms_slots
-                for i in range(k):
-                    f = frame_add(self.frame, i + 1)
-                    self._dev_frames[f % R] = f
-                self.world = final
-                self._world_checksum = batch_checks.ref(k - 1)
-                self.frame = frame_add(self.frame, k)
+            with ph.phase("stage_inputs"):
+                pk = self._stage_packed_rows(
+                    adv, self.frame, k_pad=self._ms_k,
+                    has_load=has_load, load_slot=load_slot,
+                )
+            variant = ("megastep", self._ms_k)
+            fresh = variant not in self._seen_variants
+            t_build = time.perf_counter() if fresh else 0.0
+            with ph.phase("wave_dispatch"):
+                (final, self._ms_ring, self._ms_ring_frames, stacked,
+                 checks) = self._ms_fn(
+                    self.world, self._ms_ring, self._ms_ring_frames, pk
+                )
+                self._note_dispatch_uploads(1, pk)
+                batch_checks = BatchChecks(checks)
+                if self.pipeline:
+                    self._rbq.start(batch_checks)
+            if fresh:
+                self._note_compile(variant, time.perf_counter() - t_build)
+            # host mirror of the device ring writeback (slot -> frame)
+            R = self._ms_slots
+            for i in range(k):
+                f = frame_add(self.frame, i + 1)
+                self._dev_frames[f % R] = f
+            self.world = final
+            self._world_checksum = batch_checks.ref(k - 1)
+            self.frame = frame_add(self.frame, k)
         materialize_saves = False
         if stacked is not None:
             key = ("megastep", self._ms_k)
@@ -1611,7 +1606,7 @@ class GgrsRunner:
                 "dispatch", frame=self.frame, advances=k, skipped=0,
                 donated=False, save_bytes=stacked_bytes, megastep=True,
             )
-        with ph.phase("store_save"), span("SaveWorld"):
+        with ph.phase("store_save"):
             c = 0  # advances seen so far within the run
             for r in run:
                 if isinstance(r, AdvanceRequest):

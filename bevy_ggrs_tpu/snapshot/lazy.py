@@ -210,6 +210,8 @@ class BatchChecks:
         state recompile whenever a forced pull meets a new pending mix."""
         import jax
 
+        from ..telemetry import phases
+
         pending = [b for b in cls._pending if b._host is None]
         if not pending:
             cls._pending.clear()
@@ -221,10 +223,13 @@ class BatchChecks:
         t0 = time.perf_counter()
         # batches leave the pending set only AFTER the pull succeeds: if
         # device_get raises, every batch stays pending and the next pull
-        # retries instead of orphaning them with _host=None
-        hosts = jax.device_get([b._dev for b in pending])
-        for b, h in zip(pending, hosts):
-            b._host = np.asarray(h, dtype=np.uint64)
+        # retries instead of orphaning them with _host=None.  The wait is
+        # the running tick's readback_block phase, taken out of the phase
+        # that forced it (the SyncTest comparison inside session_step).
+        with phases.phase("readback_block"):
+            hosts = jax.device_get([b._dev for b in pending])
+            for b, h in zip(pending, hosts):
+                b._host = np.asarray(h, dtype=np.uint64)
         cls._pending.clear()
         _note_readback(harvested=landed, forced=len(pending) - landed,
                        blocked_s=time.perf_counter() - t0)

@@ -8,8 +8,12 @@ This package is the single replacement surface:
 - :mod:`.metrics` — process-local registry of counters / gauges / labeled
   histograms (``rollback_depth``, ``resim_frames_total``,
   ``speculation_hit_ratio``, ``checksum_mismatch_total``, ...).
-- :mod:`.timeline` — one ordered event stream per process merging the span
-  ring, per-peer network stats and driver decisions; JSONL export.
+- :mod:`.timeline` — one ordered event stream per process merging per-peer
+  network stats and driver decisions; JSONL export.
+- :mod:`.phases` — the tick-phase timers (flight recorder, histograms,
+  profiler trace events).
+- :mod:`.compiles` — the process-wide compile account (programs built,
+  persistent-cache hits and misses, compile seconds).
 - :mod:`.forensics` — per-component checksum reports on desync, plus the
   cross-peer ``merge_reports`` alignment.
 - :mod:`.netstats` — periodic per-peer NetworkStats/TimeSync sampler
@@ -27,7 +31,9 @@ from __future__ import annotations
 
 import os
 
+from . import compiles as _compiles
 from . import devmem  # noqa: F401 (namespace re-export: telemetry.devmem)
+from .compiles import compile_account  # noqa: F401
 from .flight import (  # noqa: F401 (public re-exports)
     FlightRecorder,
     configure as configure_flight,
@@ -66,7 +72,6 @@ from .timeline import (  # noqa: F401
     Timeline,
     export_jsonl,
     record,
-    span_sink,
     timeline,
 )
 from .trace import (  # noqa: F401
@@ -86,7 +91,7 @@ __all__ = [
     "PHASES", "PhaseSet", "FlightRecorder",
     "phase_breakdown", "format_phase_table",
     "enable", "disable", "enabled", "reset", "summary",
-    "registry", "timeline", "record", "export_jsonl", "span_sink",
+    "registry", "timeline", "record", "export_jsonl", "compile_account",
     "count", "observe", "gauge_set", "percentile_from_buckets",
     "component_checksums", "configure_forensics", "forensics_dir",
     "write_desync_report", "merge_reports", "start_http_exporter",
@@ -103,19 +108,13 @@ def enabled() -> bool:
 
 
 def enable() -> None:
-    """Turn on metrics + timeline recording and hook the span ring in."""
+    """Turn on metrics + timeline recording."""
     registry().set_enabled(True)
-    from ..utils import tracing
-
-    tracing.set_span_sink(span_sink())
 
 
 def disable() -> None:
     """Turn recording back off (recorded data stays until :func:`reset`)."""
     registry().set_enabled(False)
-    from ..utils import tracing
-
-    tracing.set_span_sink(None)
 
 
 def reset() -> None:
@@ -194,6 +193,7 @@ def summary() -> dict:
 
     hits = _total("speculation_hits_total")
     misses = _total("speculation_misses_total")
+    compiles = compile_account()
     return {
         "enabled": reg.enabled,
         "metrics": snap,
@@ -209,7 +209,10 @@ def summary() -> dict:
             "host_blocked_seconds": _total("host_blocked_seconds"),
             "pipeline_degrade_total": _total("pipeline_degrade_total"),
             "latency_ms": _latency_percentiles(reg),
+            "compile_cache_hit_ratio": compiles["cache_hit_ratio"],
         },
+        # process-wide since import, recorded with telemetry off too
+        "compiles": compiles,
         "timeline_events": len(timeline()),
         "timeline_events_dropped": timeline().dropped,
         "flight_record_entries": len(flight_recorder()),
@@ -220,6 +223,8 @@ def summary() -> dict:
         "device_resident_total_bytes": devmem.total(),
     }
 
+
+_compiles.install()
 
 if os.environ.get("BGT_TELEMETRY", "").strip() in ("1", "true", "on", "yes"):
     enable()

@@ -19,19 +19,39 @@ aggregate bench gate trips.  This module gives each driver a
   ``bench.py``'s pipeline stage reconciles against wall time (the
   ``unattributed_ms <= 10%`` gate).
 
-Cost discipline (the PR-1 2% budget): each timer is a preallocated object;
-entering it is ONE boolean check when the set is off (flight recorder
-disabled AND telemetry disabled), and two ``perf_counter()`` calls plus a
-float add when on.  No registry traffic happens inside phases — histogram
-observes are batched into ``end_tick``.  The hot-loop lint
+While a JAX profiler trace is being recorded, every phase also opens a
+host trace event named after it (``jax.profiler.TraceAnnotation``) on the
+ticking thread, so the profiler's idle gaps can be put down to the phase
+the host was in.
+
+``readback_block`` is **self time**: the host blocked on a device→host
+pull (``BatchChecks.pull_pending``, the sync-mode drain).  The pull sites
+sit below the drivers (``snapshot/lazy.py``), so they reach the set whose
+tick is running through the module-level :func:`phase`, registered by
+:meth:`PhaseSet.begin_tick`.  When the block runs inside another phase
+(the deferred SyncTest comparison inside ``session_step``), its seconds
+move out of that phase, and ``sum(phases) + unattributed == wall`` still
+holds.
+
+Cost discipline (a 2% tick budget): each timer is a preallocated object.
+Entering it is ONE boolean check when the set is off (flight recorder,
+telemetry and profiler all off).  When on, it is one assignment (the open
+phase, for ``readback_block``), one boolean check (is the profiler
+recording — read once per tick in :meth:`PhaseSet.begin_tick`), two
+``perf_counter()`` calls and a float add; a trace event is built only
+while the profiler records.  No registry traffic happens inside phases —
+histogram observes are batched into ``end_tick``.  The hot-loop lint
 (``scripts/lint_imports.py``) checks every ``phase("...")`` site in the
 drivers names a catalog phase and sits inside a ``with`` block.
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation as _TraceMe
 
 from . import flight as _flight
 from .metrics import LATENCY_MS_BUCKETS, _label_key, registry
@@ -44,10 +64,17 @@ PHASES = (
     "session_step",      # session advance_frame (input/ack/checksum protocol)
     "stage_inputs",      # fill the persistent host staging buffers
     "wave_dispatch",     # fused device program submission (+ readback start)
-    "readback_harvest",  # collect landed async checksum copies / sync drain
+    "readback_harvest",  # collect landed async checksum copies (never blocks)
     "rollback_load",     # ring rollback + world restore
     "store_save",        # ring pushes + save-cell publication
+    "readback_block",    # host blocked on a device->host pull (self time)
 )
+_BLOCK = PHASES.index("readback_block")
+_ZEROS = [0.0] * len(PHASES)
+
+# The set whose tick is running (registered by PhaseSet.begin_tick, cleared
+# by end_tick): how pull sites below the drivers reach their tick's timers.
+_ACTIVE: Optional["PhaseSet"] = None
 
 
 def _quantile(sorted_vals, q: float) -> float:
@@ -118,15 +145,22 @@ class _Phase:
     the tick).  When the owning set is off, ``__enter__`` is one boolean
     check and ``__exit__`` one ``is None`` check."""
 
-    __slots__ = ("_ps", "_i", "_t0")
+    __slots__ = ("_ps", "_i", "_name", "_t0", "_tm")
 
     def __init__(self, ps: "PhaseSet", i: int):
         self._ps = ps
         self._i = i
+        self._name = PHASES[i]
         self._t0: Optional[float] = None
+        self._tm = None  # the profiler event while one is open
 
     def __enter__(self) -> "_Phase":
-        if self._ps._on:
+        ps = self._ps
+        if ps._on:
+            ps._open = self
+            if ps._trace:
+                self._tm = _TraceMe(self._name)
+                self._tm.__enter__()
             self._t0 = time.perf_counter()
         return self
 
@@ -135,7 +169,66 @@ class _Phase:
         if t0 is not None:
             self._ps._acc[self._i] += time.perf_counter() - t0
             self._t0 = None
+            if self._tm is not None:
+                self._tm.__exit__(None, None, None)
+                self._tm = None
         return False
+
+
+class _SelfPhase(_Phase):
+    """``readback_block``: a phase that may run inside another one and
+    takes its seconds out of it (self time).  Entered at the rare pull
+    sites only, so the bookkeeping of the enclosing phase is paid there."""
+
+    __slots__ = ("_outer",)
+
+    def __init__(self, ps: "PhaseSet", i: int):
+        super().__init__(ps, i)
+        self._outer: Optional[_Phase] = None
+
+    def __enter__(self) -> "_SelfPhase":
+        ps = self._ps
+        if ps._on:
+            outer = ps._open
+            # the last phase entered is still open iff its timer runs
+            self._outer = (
+                outer if outer is not None and outer._t0 is not None else None
+            )
+            if ps._trace:
+                self._tm = _TraceMe(self._name)
+                self._tm.__enter__()
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t0 = self._t0
+        if t0 is not None:
+            dt = time.perf_counter() - t0
+            acc = self._ps._acc
+            acc[self._i] += dt
+            if self._outer is not None:
+                acc[self._outer._i] -= dt
+                self._outer = None
+            self._t0 = None
+            if self._tm is not None:
+                self._tm.__exit__(None, None, None)
+                self._tm = None
+        return False
+
+
+# what phase() hands out while no recorded tick is running
+_OFF = contextlib.nullcontext()
+
+
+def phase(name: str):
+    """The ``name`` timer of the set whose tick is running, or a no-op
+    context outside a recorded tick — for timing sites below the drivers
+    (the device→host pulls of ``snapshot/lazy.py``), which cannot reach
+    the runner that called them."""
+    ps = _ACTIVE
+    if ps is None:
+        return _OFF
+    return ps._timers[name]
 
 
 class PhaseSet:
@@ -151,8 +244,13 @@ class PhaseSet:
         self.owner = owner
         self._reg = registry()
         self._acc = [0.0] * len(PHASES)
-        self._timers = {name: _Phase(self, i) for i, name in enumerate(PHASES)}
+        self._timers = {
+            name: (_SelfPhase if i == _BLOCK else _Phase)(self, i)
+            for i, name in enumerate(PHASES)
+        }
         self._on = False
+        self._trace = False  # the profiler records this tick
+        self._open: Optional[_Phase] = None  # the phase entered last
         self._t_tick = 0.0
         self._tick_rollbacks = 0
         self._tick_rollback_depth = 0
@@ -174,7 +272,7 @@ class PhaseSet:
     @property
     def on(self) -> bool:
         """Whether this tick is being recorded (set by :meth:`begin_tick`:
-        flight recorder OR telemetry enabled).  Drivers gate optional
+        flight recorder, telemetry or the profiler on).  Drivers gate optional
         ``end_tick(**extra)`` computations on it so the fully-disabled tick
         path stays one boolean check."""
         return self._on
@@ -186,9 +284,16 @@ class PhaseSet:
 
     def begin_tick(self) -> None:
         """Arm the timers for one driver tick (refreshes the on/off gate:
-        flight recorder OR telemetry enabled)."""
-        self._on = _flight._FLIGHT.enabled or self._reg.enabled
+        flight recorder, telemetry or the profiler on) and register this
+        set as the one :func:`phase` hands out."""
+        global _ACTIVE
+        self._trace = _TraceMe.is_enabled()
+        self._on = _flight._FLIGHT.enabled or self._reg.enabled or self._trace
         if self._on:
+            _ACTIVE = self
+            # time spent in timers between ticks (a pull outside any
+            # recorded tick) belongs to no tick's wall
+            self._acc[:] = _ZEROS
             self._t_tick = time.perf_counter()
             self._tick_rollbacks = 0
             self._tick_rollback_depth = 0
@@ -233,8 +338,11 @@ class PhaseSet:
         """Flush one tick's accumulations: flight entry, histograms,
         cumulative totals.  ``extra`` fields ride into the flight entry
         (e.g. ``lobbies=M`` for the batched driver)."""
+        global _ACTIVE
         if not self._on:
             return
+        if _ACTIVE is self:
+            _ACTIVE = None
         wall = time.perf_counter() - self._t_tick
         attributed = 0.0
         phases_ms = {}

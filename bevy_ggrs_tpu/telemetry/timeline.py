@@ -1,16 +1,16 @@
 """Per-frame timeline recorder — one ordered event stream per process.
 
-Merges the three previously separate views of a running session into one
-ordered stream: the span ring (``utils/tracing.py`` — SaveWorld / LoadWorld /
-AdvanceWorld / HandleRequests phases), per-peer ``network_stats`` snapshots,
-and driver decisions (rollback depth, stalls, desyncs).  Each event is a
+Merges the separate views of a running session into one ordered stream:
+per-peer ``network_stats`` snapshots and driver decisions (rollback depth,
+stalls, desyncs).  Tick phases live in the flight recorder
+(``telemetry/phases.py``), which the Chrome-trace export draws as slices.  Each event is a
 plain dict ``{"seq", "t", "kind", ...}``; events from different sessions or
 lobbies carry a ``session``/``lobby`` field, so exporting one lobby's stream
 is a filter over the shared order (the order itself is global — cross-lobby
 interleaving is exactly what a batched-server stall investigation needs).
 
 Recording is gated on the package enable flag (near-zero cost disabled) and
-bounded by a ring (``maxlen``), mirroring the span ring's memory posture.
+bounded by a ring (``maxlen``).
 Export with :meth:`Timeline.export_jsonl` / :func:`export_jsonl` — the
 ``--telemetry-out`` flag on ``scripts/profile_tick.py`` and
 ``scripts/replay_tool.py`` and the desync forensics report both ride this.
@@ -21,7 +21,7 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
-from typing import Callable, Deque, List, Optional
+from typing import Deque, List, Optional
 
 from . import metrics as _metrics
 
@@ -121,16 +121,3 @@ def record(kind: str, **fields) -> None:
 def export_jsonl(path: str, **field_filter) -> int:
     """Export the default timeline as JSONL (see :meth:`Timeline.export_jsonl`)."""
     return _TIMELINE.export_jsonl(path, **field_filter)
-
-
-def span_sink() -> Callable[[str, float, float], None]:
-    """The callback :mod:`..utils.tracing` feeds completed spans through.
-
-    Installing it (done by ``telemetry.enable()``) merges the span ring's
-    SaveWorld/LoadWorld/AdvanceWorld/... phases into the timeline as
-    ``kind="span"`` events with millisecond durations."""
-
-    def sink(name: str, t0: float, t1: float) -> None:
-        _TIMELINE.record("span", name=name, t0=t0, ms=round((t1 - t0) * 1e3, 4))
-
-    return sink

@@ -162,18 +162,13 @@ def chrome_trace(
             out.append({"ph": "i", "s": "t", "name": kind, "ts": us(e["t"]),
                         "pid": pid, "tid": tid, "args": args})
 
-    # timeline events -> instants (+ "span" slices from the legacy sink)
+    # timeline events -> instants
     have_tl_rollbacks = any(
         e.get("kind") == "rollback" for e in timeline_events
     )
     for e in timeline_events:
         kind = e.get("kind")
-        if kind == "span" and "t0" in e:
-            tid = _tid_for({"owner": "spans"}, tids, meta_events, pid)
-            out.append({"ph": "X", "name": e.get("name", "span"),
-                        "ts": us(e["t0"]), "dur": round(e.get("ms", 0) * 1e3, 3),
-                        "pid": pid, "tid": tid, "args": {}})
-        elif kind in _INSTANT_KINDS:
+        if kind in _INSTANT_KINDS:
             tid = _tid_for(e, tids, meta_events, pid)
             args = {k: v for k, v in e.items()
                     if k not in ("seq", "t", "kind", "lobby", "track")}

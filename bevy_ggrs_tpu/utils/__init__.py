@@ -12,7 +12,7 @@ from .frames import (
     frame_max,
     frame_min,
 )
-from .tracing import span, trace_log, get_trace_events
+from .tracing import trace_log
 
 __all__ = [
     "NULL_FRAME",
@@ -27,7 +27,5 @@ __all__ = [
     "frame_ge",
     "frame_max",
     "frame_min",
-    "span",
     "trace_log",
-    "get_trace_events",
 ]

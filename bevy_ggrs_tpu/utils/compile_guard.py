@@ -20,8 +20,9 @@ Usage mirrors the ``BGT_SANITIZE`` transfer sanitizer:
   naming the owner and program kind — the same sites that already emit
   the ``compile`` flight instant and ``program_compile_ms`` histogram,
   so armed runs add no parallel metric names for warmup compiles.
-* ``arm(watch_jax=True)`` additionally registers a
-  ``jax.monitoring`` listener so compiles *outside* the hooked sites
+* ``arm(watch_jax=True)`` additionally trips on every backend compile
+  the telemetry compile account's ``jax.monitoring`` listener sees
+  (``telemetry/compiles.py``), so compiles *outside* the hooked sites
   (a stray ``jax.jit`` in user code — exactly what BGT070 flags
   statically) trip the guard too.
 
@@ -81,17 +82,17 @@ class CompileGuard:
         is enabled, so engine code may call this unconditionally.
 
         ``watch_jax=True`` also trips on compiles the engine's own
-        accounting never sees (raw ``jax.jit`` in user code), via a
-        ``jax.monitoring`` backend-compile listener."""
+        accounting never sees (raw ``jax.jit`` in user code), via the
+        compile account's backend-compile listener (:func:`on_jax_compile`)."""
         if not self.enabled:
             return False
         self.armed = True
         if watch_jax:
             self.watch_jax = True
-            _install_jax_listener()
         return True
 
     def disarm(self) -> None:
+        """Back to warmup: compiles pass again (and ``watch_jax`` ends)."""
         self.armed = False
         self.watch_jax = False
 
@@ -108,28 +109,14 @@ class CompileGuard:
 
 _GUARD = CompileGuard()
 
-# jax.monitoring listener registration is append-only (no unregister),
-# so install at most one process-wide listener that defers to the
-# current singleton's armed/watch_jax state.
-_JAX_LISTENER_INSTALLED = False
 
-
-def _install_jax_listener() -> None:
-    global _JAX_LISTENER_INSTALLED
-    if _JAX_LISTENER_INSTALLED:
-        return
-    try:
-        from jax import monitoring as _mon
-    except ImportError:  # pragma: no cover - jax always present in CI
-        return
-
-    def _on_event(event: str, duration: float, **kw) -> None:
-        g = _GUARD
-        if g.armed and g.watch_jax and "backend_compile" in event:
-            g._trip("jax", event, duration * 1e3)
-
-    _mon.register_event_duration_secs_listener(_on_event)
-    _JAX_LISTENER_INSTALLED = True
+def on_jax_compile(event: str, duration: float) -> None:
+    """Backend-compile hook of the process's one ``jax.monitoring``
+    listener (``telemetry/compiles.py``; listeners cannot be unregistered,
+    so the guard defers to the current singleton's state)."""
+    g = _GUARD
+    if g.armed and g.watch_jax:
+        g._trip("jax", event, duration * 1e3)
 
 
 def guard() -> CompileGuard:

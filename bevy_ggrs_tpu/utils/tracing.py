@@ -1,77 +1,17 @@
-"""Lightweight span tracing for the host-side driver.
+"""Debug logging for the host-side driver.
 
-The reference wraps request handling and each schedule in tracing spans
-("ggrs"/"HandleRequests", "SaveWorld", "LoadWorld", "AdvanceWorld" —
-/root/reference/src/schedule_systems.rs:171,224-253) and relies on the host
-engine's tracing backend.  Here ``span`` feeds two sinks: stdlib logging
-(always) and the telemetry timeline when enabled (``set_span_sink`` — the
-timeline then carries the spans into ``telemetry.chrome_trace()`` as
-Perfetto slices).  The JAX profiler covers the device side
-(``jax.profiler.trace``).
-
-The module-local ``(name, t0, t1)`` ring this module once kept is gone:
-phase attribution moved to :mod:`..telemetry.phases` (exact per-phase
-timers with flight-recorder persistence) and span *export* to
-:mod:`..telemetry.trace`.  ``get_trace_events`` / ``clear_trace_events``
-remain as deprecated no-op shims so old callers keep importing.
+Tick phases are timed by :mod:`..telemetry.phases` (flight recorder,
+histograms, and host events on the JAX profiler's trace); the JAX profiler
+covers the device side (``jax.profiler.trace``).
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
-import time
-from typing import Callable, Optional
 
 logger = logging.getLogger("bevy_ggrs_tpu")
-
-_ENABLED = True
-_SPAN_SINK: Optional[Callable[[str, float, float], None]] = None
-
-
-def set_tracing(enabled: bool) -> None:
-    """Globally enable/disable span recording."""
-    global _ENABLED
-    _ENABLED = enabled
-
-
-def set_span_sink(sink: Optional[Callable[[str, float, float], None]]) -> None:
-    """Install a callback fed every completed span as ``(name, t0, t1)``.
-
-    The telemetry timeline (``telemetry.enable()``) installs its sink here;
-    None uninstalls.  The sink runs inside the span's ``finally`` — keep it
-    cheap and non-raising."""
-    global _SPAN_SINK
-    _SPAN_SINK = sink
-
-
-@contextlib.contextmanager
-def span(name: str):
-    """Context manager recording a named wall-clock span."""
-    if not _ENABLED:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        t1 = time.perf_counter()
-        if _SPAN_SINK is not None:
-            _SPAN_SINK(name, t0, t1)
-        logger.debug("span %s: %.3f ms", name, (t1 - t0) * 1e3)
 
 
 def trace_log(msg: str, *args) -> None:
     """Debug-level log line on the framework logger."""
     logger.debug(msg, *args)
-
-
-def get_trace_events():
-    """Deprecated: the module-local span ring is gone.  Always returns
-    ``[]``.  Use ``telemetry.flight_recorder().snapshot("tick")`` for phase
-    attribution or ``telemetry.chrome_trace()`` for span export."""
-    return []
-
-
-def clear_trace_events() -> None:
-    """Deprecated no-op (see :func:`get_trace_events`)."""

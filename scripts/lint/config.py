@@ -54,6 +54,8 @@ PHASES_MODULE = "bevy_ggrs_tpu/telemetry/phases.py"
 PHASE_FILES: Tuple[str, ...] = (
     "bevy_ggrs_tpu/runner.py",
     "bevy_ggrs_tpu/batch_runner.py",
+    # readback_block: the device->host pulls below the drivers
+    "bevy_ggrs_tpu/snapshot/lazy.py",
 )
 
 # -- metric-name <-> docs-catalog cross-check --------------------------------

@@ -10,7 +10,7 @@ compares each pair after normalizing both ASTs:
 - docstrings dropped, type annotations stripped,
 - argument/local names renamed to positional placeholders in first-use
   order (``self`` and free/global names keep their spelling),
-- string literals inside telemetry/phase calls (``span("...")``,
+- string literals inside telemetry/phase calls (``.phase("...")``,
   ``.record("...")``, ``telemetry.count("...")``) blanked, so a
   ``"rollback"`` vs ``"batched_rollback"`` label is not drift.
 
@@ -43,7 +43,7 @@ rule(
 
 # calls whose string-literal args are labels, not semantics
 _LABEL_CALL_ATTRS = frozenset({
-    "span", "phase", "record", "count", "observe", "gauge_set", "inc",
+    "phase", "record", "count", "observe", "gauge_set", "inc",
     "observe_key", "set_key",
 })
 

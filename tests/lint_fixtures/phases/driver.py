@@ -1,5 +1,7 @@
 """Fixture driver exercising BGT020/BGT021 against phases.py's catalog."""
 
+import types
+
 
 class _T:
     def phase(self, name):
@@ -12,11 +14,16 @@ class _T:
         return False
 
 
+# the module-level timer of the ticking set (a pull site below the driver)
+phases = types.SimpleNamespace(phase=lambda name: _T())
+
+
 def run(t: _T, dynamic: str):
     with t.phase("inputs"):
         pass
     with t.phase("advance"):
-        pass
+        with phases.phase("blocked"):  # nested self-time phase: clean
+            pass
     with t.phase("typo_phase"):      # BGT020: not in the catalog
         pass
     with t.phase(dynamic):           # BGT020: not one string literal

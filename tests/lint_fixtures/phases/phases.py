@@ -4,5 +4,6 @@ PHASES = (
     "inputs",
     "advance",
     "checksum",
+    "blocked",
     "never_timed",
 )

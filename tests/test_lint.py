@@ -281,7 +281,7 @@ def test_phase_reverse_check_skipped_on_partial_corpus():
 
 def test_extract_phase_catalog(tmp_path):
     cat = extract_phase_catalog(FIXTURES / "phases" / "phases.py")
-    assert cat == {"inputs", "advance", "checksum", "never_timed"}
+    assert cat == {"inputs", "advance", "checksum", "blocked", "never_timed"}
     assert extract_phase_catalog(tmp_path / "missing.py") is None
     bad = tmp_path / "dynamic.py"
     bad.write_text("PHASES = tuple(x for x in names)\n")
@@ -303,7 +303,7 @@ def test_real_catalog_extracts_and_matches_package():
     """The real telemetry/phases.py catalog must stay AST-extractable —
     that is the contract replacing the old hand-mirrored copy."""
     cat = extract_phase_catalog(ROOT / "bevy_ggrs_tpu/telemetry/phases.py")
-    assert cat and "session_step" in cat
+    assert cat and {"session_step", "readback_block"} <= cat
 
 
 # -- metric and rule docs cross-checks (BGT03x / BGT05x) ----------------------

@@ -1,7 +1,9 @@
 """Tick-phase latency attribution tests — histogram percentiles, guarded
 phase timers (disabled-path cost + enabled-path series), the always-on
 flight recorder (ring bound, dump, desync embedding, reconciliation), the
-bench-history regression gate, and the lint's mirrored phase catalog."""
+bench-history regression gate, the lint's mirrored phase catalog,
+``readback_block`` self time, phase events on the JAX profiler's trace,
+and the process-wide compile account."""
 
 import dataclasses
 import importlib.util
@@ -9,6 +11,7 @@ import json
 import os
 import time
 
+import numpy as np
 import pytest
 
 from bevy_ggrs_tpu import telemetry
@@ -475,3 +478,244 @@ def test_lint_check_phases_flags_misuse():
     assert any("one string literal" in m for m in msgs)
     good = ast.parse("with ps.phase('wave_dispatch'):\n    pass\n")
     assert lint.check_phases(good) == []
+
+
+# ------------------------------------------- readback_block (self time)
+
+
+def _assert_tick_invariant(entries):
+    """sum(phases) + unattributed == wall for every flight tick entry."""
+    assert entries
+    for e in entries:
+        total = sum(e["phases"].values()) + e["unattributed_ms"]
+        assert total == pytest.approx(e["wall_ms"], abs=1e-3), e
+
+
+def test_readback_block_is_self_time_inside_session_step():
+    from bevy_ggrs_tpu.telemetry import phases
+
+    ps = telemetry.PhaseSet(owner="solo")
+    ps.begin_tick()
+    with ps.phase("session_step"):
+        time.sleep(0.002)
+        # a pull site below the driver reaches the ticking set
+        with phases.phase("readback_block"):
+            time.sleep(0.02)
+        time.sleep(0.002)
+    ps.end_tick(frame=1)
+    (e,) = telemetry.flight_recorder().snapshot("tick")
+    assert e["phases"]["readback_block"] >= 20.0
+    # the block's seconds moved out of the enclosing phase
+    assert 4.0 <= e["phases"]["session_step"] < 15.0
+    _assert_tick_invariant([e])
+    t = ps.totals()
+    assert t["phase_seconds"]["readback_block"] >= 0.02
+    assert t["phase_seconds"]["session_step"] < 0.015
+    assert t["wall_seconds"] == pytest.approx(
+        t["attributed_seconds"] + t["unattributed_seconds"], abs=1e-5
+    )
+
+
+def test_readback_block_outside_any_phase_or_tick():
+    from bevy_ggrs_tpu.telemetry import phases
+
+    ps = telemetry.PhaseSet(owner="solo")
+    ps.begin_tick()
+    with ps.phase("wave_dispatch"):
+        pass
+    # inside the tick but after the last phase closed: nothing to move
+    with phases.phase("readback_block"):
+        time.sleep(0.005)
+    ps.end_tick()
+    # between ticks the module-level timer is a no-op
+    with phases.phase("readback_block"):
+        time.sleep(0.005)
+    ps.begin_tick()
+    with ps.phase("store_save"):
+        pass
+    ps.end_tick()
+    first, second = telemetry.flight_recorder().snapshot("tick")
+    assert first["phases"]["readback_block"] >= 5.0
+    assert first["phases"]["wave_dispatch"] >= 0.0
+    assert "readback_block" not in second["phases"]
+    _assert_tick_invariant([first, second])
+
+
+class _SlowHostChecks:
+    """A host-backed checksum batch whose copy never lands on its own:
+    harvest leaves it pending, so the session's comparison forces it
+    through ``BatchChecks.pull_pending`` (``jax.device_get`` reads it
+    through ``__array__``, which stands in for the device wait)."""
+
+    def __init__(self, delay_s: float):
+        self.delay_s = delay_s
+
+    def is_ready(self) -> bool:
+        return False
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.delay_s)
+        return np.zeros((2, 2), dtype=dtype or np.uint32)
+
+
+def test_forced_pull_inside_session_step_lands_in_readback_block():
+    from bevy_ggrs_tpu.snapshot.lazy import BatchChecks, readback_stats
+
+    app = make_counter_app()
+    runner, mismatches = make_runner(app, check_distance=2)
+    for _ in range(4):
+        runner.tick()
+    forced0 = readback_stats()["forced"]
+    # two equal checksums of a far frame: the SyncTest comparison of the
+    # next tick forces them (CPU compares every tick), inside session_step
+    batch = BatchChecks(_SlowHostChecks(0.03))
+    runner.session._cells[10_000] = [batch.ref(0), batch.ref(1)]
+    telemetry.flight_recorder().clear()
+    runner.tick()
+    assert mismatches == []
+    assert readback_stats()["forced"] == forced0 + 1
+    (e,) = telemetry.flight_recorder().snapshot("tick")
+    assert e["phases"]["readback_block"] >= 30.0
+    assert e["phases"]["session_step"] < 30.0
+    _assert_tick_invariant([e])
+    # the forensics entry stays beside it
+    (fr,) = telemetry.flight_recorder().snapshot("forced_readback")
+    assert fr["blocked_ms"] >= 30.0
+    assert runner.stats()["phases"]["phase_seconds"]["readback_block"] >= 0.03
+
+
+def test_sync_mode_drain_wait_is_readback_block():
+    app = make_counter_app()
+    runner, mismatches = make_runner(app, check_distance=2)
+    runner.pipeline = False
+    for _ in range(6):
+        runner.tick()
+    assert mismatches == []
+    entries = telemetry.flight_recorder().snapshot("tick")
+    assert all("readback_block" in e["phases"] for e in entries[-3:])
+    _assert_tick_invariant(entries)
+
+
+# ------------------------------------------------ phases on the profiler
+
+
+class _CountingTraceMe:
+    """Stands in for the profiler's TraceMe: counts the events built."""
+
+    enabled = False
+    built: list = []
+
+    def __init__(self, name):
+        _CountingTraceMe.built.append(name)
+
+    @staticmethod
+    def is_enabled():
+        return _CountingTraceMe.enabled
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_no_trace_event_built_while_the_profiler_is_off(monkeypatch):
+    from bevy_ggrs_tpu.telemetry import phases
+
+    monkeypatch.setattr(phases, "_TraceMe", _CountingTraceMe)
+    monkeypatch.setattr(_CountingTraceMe, "built", [])
+    app = make_counter_app()
+    runner, _ = make_runner(app, check_distance=2)
+    for _ in range(5):
+        runner.tick()
+    assert runner.stats()["phases"]["ticks"] == 5
+    assert _CountingTraceMe.built == []
+    # recording: one event per phase entry, named as in the catalog
+    monkeypatch.setattr(_CountingTraceMe, "enabled", True)
+    for _ in range(3):
+        runner.tick()
+    assert _CountingTraceMe.built
+    assert set(_CountingTraceMe.built) <= set(telemetry.PHASES)
+    assert {"session_step", "wave_dispatch"} <= set(_CountingTraceMe.built)
+
+
+def test_profiler_trace_holds_the_phase_names(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    app = make_counter_app()
+    runner, _ = make_runner(app, check_distance=2)
+    for _ in range(4):
+        runner.tick()  # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(4):
+            runner.tick()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    names = set()
+    for plane in pd.planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    assert {"session_step", "wave_dispatch", "store_save"} <= names
+    # none of the benchmark's own annotation names
+    assert not {"window", "tick", "net_deliver", "input_gen"} & set(
+        telemetry.PHASES
+    )
+
+
+# ------------------------------------------------------- compile account
+
+
+def test_compile_account_counts_a_fresh_jit():
+    import jax
+
+    x = jax.device_put(np.arange(7, dtype=np.float32))
+    before = telemetry.compile_account()
+    out = jax.jit(lambda x: x * 3 + 1)(x)
+    after = telemetry.compile_account()
+    assert np.asarray(out)[2] == 7.0
+    assert after["programs"] == before["programs"] + 1
+    for k in ("trace_s", "lower_s", "backend_s"):
+        assert after[k] > before[k], k
+    assert telemetry.summary()["compiles"]["programs"] == after["programs"]
+
+
+def test_compile_account_cache_events_mirror_to_registry():
+    from jax import monitoring
+
+    telemetry.enable()
+    before = telemetry.compile_account()
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.25
+    )
+    after = telemetry.compile_account()
+    assert after["cache_hits"] == before["cache_hits"] + 2
+    assert after["cache_misses"] == before["cache_misses"] + 1
+    assert after["programs"] == before["programs"] + 1
+    assert after["backend_s"] == pytest.approx(before["backend_s"] + 0.25)
+    reg = telemetry.registry()
+    assert reg.counter("compile_cache_hits_total", "").value() == 2
+    assert reg.counter("compile_cache_misses_total", "").value() == 1
+    assert reg.counter("compile_programs_total", "").value() == 1
+    assert reg.counter("compile_seconds_total", "").value(
+        stage="backend") == pytest.approx(0.25)
+    ratio = after["cache_hits"] / (after["cache_hits"] + after["cache_misses"])
+    assert reg.gauge("compile_cache_hit_ratio", "").value() == pytest.approx(
+        ratio)
+    text = reg.render_prometheus()
+    assert "compile_cache_hit_ratio" in text
+
+
+def test_steady_ticks_never_reach_the_compile_account():
+    app = make_counter_app()
+    runner, _ = make_runner(app, check_distance=2)
+    for _ in range(8):
+        runner.tick()  # warm every program the steady ticks use
+    before = telemetry.compile_account()
+    for _ in range(8):
+        runner.tick()
+    assert telemetry.compile_account() == before

@@ -109,8 +109,23 @@ def test_timeline_orders_rollbacks_and_spans():
     for ev in rollbacks:
         assert ev["to_frame"] < ev["from_frame"]
         assert ev["depth"] == ev["from_frame"] - ev["to_frame"]
-    span_names = {e["name"] for e in telemetry.timeline().events("span")}
-    assert {"SaveWorld", "LoadWorld", "AdvanceWorld"} <= span_names
+    # the tick phases are the one span system: chrome_trace() draws each
+    # flight tick as a slice with one child slice per timed phase, inside
+    # its tick and after the rollbacks' timeline order
+    trace = telemetry.chrome_trace()
+    assert telemetry.validate_chrome_trace(trace) == []
+    slices = [e for e in trace["traceEvents"] if e.get("ph") == "X"]
+    ticks = [e for e in slices if e["name"] == "tick"]
+    phase_slices = [e for e in slices if e["name"] in telemetry.PHASES]
+    assert len(ticks) == len(telemetry.flight_recorder().snapshot("tick"))
+    assert {"store_save", "rollback_load", "wave_dispatch",
+            "session_step"} <= {e["name"] for e in phase_slices}
+    for e in phase_slices:
+        assert any(t["tid"] == e["tid"] and t["ts"] <= e["ts"] + 1e-3
+                   and e["ts"] + e["dur"] <= t["ts"] + t["dur"] + 1e-3
+                   for t in ticks), e
+    assert not [e for e in slices
+                if e["name"] not in telemetry.PHASES and e["name"] != "tick"]
     # summary() derives the headline numbers from the same run
     s = telemetry.summary()
     assert s["enabled"] and s["derived"]["rollbacks_total"] == len(rollbacks)
