@@ -8,7 +8,8 @@ decrement ttl, despawn on expiry; the RNG state is itself rollback state
 so resimulated frames reproduce identical spawns; Transform participates in
 the checksum via its raw f32 bit pattern (particles.rs:207-222).
 
-TPU-native shape: a fixed-capacity pool, ``spawn_many`` scatter per frame, a
+TPU-native shape: a fixed-capacity pool, a ``spawn_many`` scatter of the
+``rate`` new rows per frame (never a pass that gathers for every slot), a
 counter-based PRNG (one uint32 counter resource -> ``jax.random`` key per
 frame — the rollback-able equivalent of the sequential Xoshiro), and all
 physics as masked SoA ops.

@@ -12,8 +12,10 @@ Correctness notes:
   (snapshot/checksum.py:12-19) — associative/commutative integer arithmetic,
   exact under any sharding (a plain psum), so sharded and single-device runs
   produce bit-identical checksums as long as the state bits match;
-- ``spawn``/``spawn_many`` use cumsum/argmax over the sharded axis, which XLA
-  lowers to scan+collectives — deterministic regardless of layout.
+- ``spawn`` takes an argmax and ``spawn_many`` a cumsum of the free mask over
+  the sharded axis (a small burst then searches that cumsum and
+  scatters its rows into the sharded columns); XLA inserts the collectives,
+  and the result is deterministic regardless of layout.
 """
 
 from __future__ import annotations
