@@ -20,20 +20,25 @@ world.  Each server tick it:
    (ops/batch.BucketedWaveExecutor: smallest power-of-two depth bucket
    covering the wave's ``k_hot``, exact unmasked program for full waves,
    ``n_real``-masked program for ragged ones), and serves Load ops from
-   per-lobby snapshot rings via ONE fused mixed-source gather — lobbies
-   loading rows of *different* past stacked buffers are grouped per buffer
-   (snapshot/lazy.plan_row_gather) and scattered into the resident world in
-   a single jitted program.
+   per-lobby snapshot rings.  A load of the lobby's OWN past row (its lane
+   of a past wave output or resident world) rides the lobby's next run
+   wave: the packed prefix's ``has_load``/``load_slot`` words select the
+   row inside the wave program, lane-locally, with no dispatch of its own
+   (``_defer_loads``).  Every other load — rows of *different* past
+   buffers, non-identity strategies, speculation, a list ending on a
+   Load — is served by ONE fused mixed-source gather: lobbies are grouped
+   per buffer (snapshot/lazy.plan_row_gather) and scattered into the
+   resident world in a single jitted program.
 
 The steady-state tick therefore costs a CONSTANT number of device
-dispatches independent of the lobby count M (one per load wave, one per
-run wave, plus one fused ``store_state`` dispatch for non-identity
-strategies) — verified by the dispatch-flatness gate in bench.py's batched
-stage.  Inputs stage through persistent preallocated host buffers (no
-per-tick allocation), and saves store ``LazySlice(stacked, (lobby,
-frame_idx))`` handles — one ``[M, K, ...]`` buffer per wave backs every
-lobby's ring rows, with checksum pulls riding the process-wide BatchChecks
-fusion (snapshot/lazy.py).
+dispatches independent of the lobby count M (one per run wave, one per
+load wave that could not ride a run wave, plus one fused ``store_state``
+dispatch for non-identity strategies) — verified by the dispatch-flatness
+gate in bench.py's batched stage.  Inputs stage through persistent
+preallocated host buffers (no per-tick allocation), and saves store
+``LazySlice(stacked, (lobby, frame_idx))`` handles — one ``[M, K, ...]``
+buffer per wave backs every lobby's ring rows, with checksum pulls riding
+the process-wide BatchChecks fusion (snapshot/lazy.py).
 
 Bit-equality caveat (same as ops/batch.py): the vmapped program is a
 DIFFERENT XLA program than the single-lobby one, so for variant-unstable
@@ -46,6 +51,7 @@ modes are refused for the same reason (make_batched_resim_fn docstring).
 
 from __future__ import annotations
 
+import gc
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -80,6 +86,7 @@ from .snapshot.lazy import (
     readback_queue,
 )
 from .snapshot.ring import SnapshotRing, rollback_many
+from .telemetry.compiles import programs_built
 from .utils import compile_guard
 from .utils.frames import NULL_FRAME, frame_add
 
@@ -217,6 +224,12 @@ class BatchedRunner:
                     f"window ({w}) — see GgrsRunner.set_session"
                 )
         self.k_max = k_max if k_max is not None else max(windows) + 1
+        # SyncTest lobbies started together would all run their deferred
+        # comparisons on the same tick, one tick in every interval paying
+        # every lobby's; give each lobby its own phase of the interval
+        for b, s in enumerate(self.sessions):
+            if isinstance(s, SyncTestSession):
+                s.stagger_compares(b * s.compare_interval() // m)
         self.read_inputs = read_inputs or (
             lambda lobby, handles: {h: app.zero_inputs()[h] for h in handles}
         )
@@ -298,6 +311,10 @@ class BatchedRunner:
         self.device_dispatches = 0
         self.fused_loads = 0
         self.fallback_loads = 0
+        self.in_wave_loads = 0
+        # loads handed to a later run wave: wave index -> (source buffer,
+        # {lobby: stored LazySlice}); consumed by that wave's _do_runs
+        self._deferred: Dict[int, tuple] = {}
         self.stalled = [0] * m
         self._np = self.sessions[0].num_players()
         for s in self.sessions:
@@ -436,6 +453,11 @@ class BatchedRunner:
             "fallback_load_rows_total",
             "load rows served by per-lobby scatter (non-LazySlice snapshot)",
         )
+        self._m_loads = _treg.bind_counter(
+            "batched_loads_total",
+            "rollback loads by path: in_wave (selected inside the next run "
+            "wave) or separate (the fused load dispatch)",
+        )
 
     # -- per-tick driver ----------------------------------------------------
 
@@ -445,6 +467,7 @@ class BatchedRunner:
         self._m_ticks.inc()
         ph = self._phases
         ph.begin_tick()
+        built0 = programs_built()
         if self.pipeline:
             # harvest last tick's landed checksum copies before the lobby
             # polls publish them (never blocks)
@@ -461,7 +484,7 @@ class BatchedRunner:
                 ops[w] if w < len(ops) else None for ops in per_lobby_ops
             ]
             self._do_loads(wave_ops, per_lobby_ops, w)
-            self._do_runs(wave_ops)
+            self._do_runs(wave_ops, w)
         if self.spec_caches is not None:
             # hedge the tick's predicted transitions into the lanes the last
             # run wave left idle (draft capacity, not extra census)
@@ -470,6 +493,8 @@ class BatchedRunner:
             cf = s.confirmed_frame()
             self.confirmed[b] = cf
             self.rings[b].confirm(cf)
+        if programs_built() != built0:
+            _settle_heap()
         if n_waves:
             # handshake-only ticks (no lobby emitted an op) stay out of the
             # flight ring — they would evict the interesting entries.  The
@@ -682,8 +707,10 @@ class BatchedRunner:
             entries = rollback_many(
                 self.rings, [(b, f) for b, f, _c in loads]
             )
+            deferred = self._defer_loads(entries, per_lobby_ops, w)
             groups, fallback = plan_row_gather(
-                [(b, stored) for b, (stored, _cs) in entries]
+                [(b, stored) for b, (stored, _cs) in entries
+                 if b not in deferred]
             )
             if groups:
                 self.worlds = fused_load_rows(
@@ -693,6 +720,9 @@ class BatchedRunner:
                 self.fused_loads += 1
                 self._m_dispatches.inc()
                 self._m_fused_loads.inc()
+                self._m_loads.inc(
+                    sum(len(g[3]) for g in groups), path="separate"
+                )
             for b, stored in fallback:
                 # rare path: a ring entry that is a concrete pytree (not a
                 # LazySlice into a stacked buffer) — per-lobby scatter
@@ -720,10 +750,57 @@ class BatchedRunner:
                 path="miss",
             )
 
+    def _defer_loads(
+        self, entries, per_lobby_ops, w: int
+    ) -> Dict[int, LazySlice]:
+        """Hand the wave's loads of each lobby's OWN past row to that
+        lobby's next run wave (``w + 1``), where the wave program selects
+        the row lane-locally (``BucketedWaveExecutor.stage_loads``) — the
+        load costs no dispatch, no index upload and, sharded, no collective.
+
+        Deferral needs the packed prefix, the identity strategy (no
+        ``load_state`` transform) and speculation off; under those every
+        ``LazySlice`` ring entry is a row of a past wave output or resident
+        world, both ``[M_pad, ...]``.  A load then qualifies when its entry
+        is a ``LazySlice`` into lane ``b`` and the lobby's next op is a run
+        with at least one advance (so that wave dispatches).  When the
+        qualifying loads read more than one buffer none is deferred.  The
+        host bookkeeping (ring, frame, checksum) stays with the caller.
+        Returns the deferred ``{lobby: stored}``."""
+        if (not self.packed or self._load_transform is not None
+                or self.spec_caches is not None or per_lobby_ops is None):
+            return {}
+        picked: Dict[int, LazySlice] = {}
+        buf = None
+        for b, (stored, _cs) in entries:
+            if not isinstance(stored, LazySlice):
+                continue
+            if _lane_slot(stored)[0] != b:
+                continue
+            ops_b = per_lobby_ops[b]
+            nxt = ops_b[w + 1] if w + 1 < len(ops_b) else None
+            if nxt is None or not any(
+                isinstance(r, AdvanceRequest) for r in (nxt.run or ())
+            ):
+                continue
+            if buf is None:
+                buf = stored._stacked
+            elif stored._stacked is not buf:
+                return {}  # two source buffers: the fused load serves all
+            picked[b] = stored
+        if picked:
+            self._deferred[w + 1] = (buf, picked)
+            self.in_wave_loads += len(picked)
+            self._m_loads.inc(len(picked), path="in_wave")
+        return picked
+
     # -- runs ---------------------------------------------------------------
 
-    def _do_runs(self, wave_ops: List[Optional[_Op]]) -> None:
+    def _do_runs(self, wave_ops: List[Optional[_Op]], w: int) -> None:
         m = len(self.sessions)
+        # loads _defer_loads handed to this wave: their lanes start from
+        # their own row of `load_src` inside the wave program
+        load_src, in_wave = self._deferred.pop(w, (None, {}))
         runs = [op.run if op is not None else None for op in wave_ops]
         adv = [
             [r for r in (run or []) if isinstance(r, AdvanceRequest)]
@@ -765,8 +842,14 @@ class BatchedRunner:
                         kb = len(a)
                         lane = packed[b]
                         # prefix rewritten EVERY wave: an idle lane must
-                        # read n_real=0 even if a past wave left payload
-                        pack_prefix(lane, self.frames[b], kb)
+                        # read n_real=0 (and no load) even if a past wave
+                        # left payload
+                        stored = in_wave.get(b)
+                        if stored is None:
+                            pack_prefix(lane, self.frames[b], kb)
+                        else:
+                            pack_prefix(lane, self.frames[b], kb, 1,
+                                        _lane_slot(stored)[1])
                         for i, x in enumerate(a):
                             pack_row(pspec, lane, i, x.inputs, x.status)
                         repeat_last_row(lane, kb, bucket)
@@ -803,6 +886,8 @@ class BatchedRunner:
                 wave_ks = ks + [0] * (self._m_pad - m)
             with ph.phase("wave_dispatch"):
                 if self.packed:
+                    if in_wave:
+                        self.exec.stage_loads(load_src)
                     bucket, finals, stacked, checks_flat = (
                         self.exec.run_wave_packed(
                             self.worlds, self._stage_packed, wave_ks
@@ -847,8 +932,10 @@ class BatchedRunner:
                 if c == 0:
                     # pre-dispatch save: slice the PREVIOUS resident world's
                     # row (still alive in prev_worlds); its checksum handle
-                    # was tracked, not recomputed
-                    handles.append(LazySlice(prev_worlds, b))
+                    # was tracked, not recomputed.  A lane loading in this
+                    # wave saves its loaded entry: its prev_worlds row
+                    # predates the load
+                    handles.append(in_wave.get(b) or LazySlice(prev_worlds, b))
                 else:
                     handles.append(LazySlice(stacked, (b, c - 1)))
             if not identity:
@@ -1019,8 +1106,8 @@ class BatchedRunner:
 
     def stats(self) -> dict:
         """Driver + executor counters: ticks, rollbacks, device dispatches,
-        fused/fallback load counts, per-lobby frame state, and the wave
-        executor's compile/dispatch/bucket histogram stats."""
+        fused/fallback/in-wave load counts, per-lobby frame state, and the
+        wave executor's compile/dispatch/bucket histogram stats."""
         out = {
             "lobbies": len(self.sessions),
             "packed": self.packed,
@@ -1029,6 +1116,7 @@ class BatchedRunner:
             "device_dispatches": self.device_dispatches,
             "fused_loads": self.fused_loads,
             "fallback_loads": self.fallback_loads,
+            "in_wave_loads": self.in_wave_loads,
             "stalled_frames": list(self.stalled),
             "frames": list(self.frames),
             "confirmed": list(self.confirmed),
@@ -1084,6 +1172,33 @@ class BatchedRunner:
                         self.on_mismatch(b, e)
                     else:
                         raise
+
+
+def _settle_heap() -> None:
+    """After a tick that built programs: collect once, then freeze what
+    survives (``gc.freeze``).  A full collection walks every tracked
+    object, and JAX's traced programs and caches make that over 100k
+    objects, tens of ms a collection; a many-lobby tick loop promotes
+    enough objects to run one every few dozen ticks, so those
+    collections set the tick's tail.  Frozen, set-up's objects drop out
+    of later collections, which walk only what ticks allocated since.
+    Frozen objects still die by reference count; a cycle among them is
+    collected at the next tick that builds a program (unfreeze,
+    collect, freeze).  A process that turned the collector off keeps
+    it off."""
+    if not gc.isenabled():
+        return
+    gc.unfreeze()
+    gc.collect()
+    gc.freeze()
+
+
+def _lane_slot(stored: LazySlice):
+    """``(lane, slot)`` a ring entry names: ``stacked[lane, slot]`` of a
+    stacked wave output, or ``(lane, 0)`` for a row of a world-shaped
+    buffer."""
+    i = stored._i
+    return i if isinstance(i, tuple) else (i, 0)
 
 
 # -- jitted row helpers (one dispatch each; compiled once) -------------------

@@ -173,6 +173,26 @@ def make_batched_exact_fn(app, k: int, *, unroll: int = 1,
     return jax.jit(recycling, donate_argnums=(4, 5))
 
 
+def _lane_load(w, src, has_load, load_slot):
+    """A lane's in-wave rollback load: where the packed prefix's
+    ``has_load`` word is set, the lane starts from its OWN row of the load
+    source instead of the resident world — slot ``load_slot`` of a stacked
+    ``[k', ...]`` source (a past wave's saved frames), or the whole row of a
+    world-shaped source (a past resident world).  A per-lane select, not a
+    gather across lanes: no index array rides along, and the lobby-sharded
+    form needs no collective.  The picked values are copied bit for bit, so
+    the wave matches a separate load followed by the plain wave."""
+    take = has_load != 0
+
+    def pick(a, s):
+        if s.ndim > a.ndim:
+            s = jax.lax.dynamic_index_in_dim(s, load_slot, axis=0,
+                                             keepdims=False)
+        return jnp.where(take, s, a)
+
+    return jax.tree.map(pick, w, src)
+
+
 def make_batched_packed_padded_fn(app, k: int, *, unroll: int = 1,
                                   fused_checksums: bool = False):
     """Packed single-upload variant of :func:`make_batched_padded_fn`:
@@ -184,7 +204,14 @@ def make_batched_packed_padded_fn(app, k: int, *, unroll: int = 1,
     ride as separate uploads are folded into the one buffer — a wave costs
     one host->device upload total instead of four.  The unpack is a pure
     bitcast; arithmetic is unchanged, so lanes stay bit-identical to the
-    unpacked program (tests/test_packed.py)."""
+    unpacked program (tests/test_packed.py).
+
+    Called as ``fn(batched_world, packed_b, src_b)`` the program also
+    serves in-wave rollback loads: ``src_b`` is one past buffer with the
+    lobby axis leading (a stacked wave output or a past resident world),
+    vmapped with the worlds, and each lane whose prefix sets ``has_load``
+    starts from its own row of it (:func:`_lane_load`).  Without ``src_b``
+    the traced program is the plain wave."""
     if app.canonical_depth is not None or app.canonical_branches is not None:
         raise ValueError(
             "many-worlds batching is incompatible with canonical mode "
@@ -196,15 +223,21 @@ def make_batched_packed_padded_fn(app, k: int, *, unroll: int = 1,
     seed, retention = app.seed, app.retention
     pspec = app.packed_spec
 
-    def lane(w, pk):
-        inputs, status, start, n_real, _hl, _ls = unpack_seq(pspec, pk)
+    def lane(w, pk, *src):
+        inputs, status, start, n_real, has_load, load_slot = unpack_seq(
+            pspec, pk
+        )
+        if src:
+            w = _lane_load(w, src[0], has_load, load_slot)
         return resim_padded(
             reg, step, w, inputs, status, start, n_real, retention, fps,
             seed, unroll=unroll, fused_checksums=fused_checksums,
         )
 
-    def body(batched_world, packed_b):
-        finals, stacked, checks = jax.vmap(lane)(batched_world, packed_b)
+    def body(batched_world, packed_b, *src_b):
+        finals, stacked, checks = jax.vmap(lane)(
+            batched_world, packed_b, *src_b
+        )
         return finals, stacked, checks.reshape(-1, 2)
 
     return jax.jit(body)
@@ -219,7 +252,9 @@ def make_batched_packed_exact_fn(app, k: int, *, unroll: int = 1,
     per-lane prefix supplies the start frame (``n_real`` is ignored — every
     lane advances exactly ``k``).  ``donate_outputs=True`` appends the
     previous call's ``(prev_stacked, prev_checks)`` as donated parameters,
-    same recycling contract as the unpacked builder."""
+    same recycling contract as the unpacked builder.  Without donation the
+    program takes the optional in-wave load source ``src_b`` of
+    :func:`make_batched_packed_padded_fn`."""
     if app.canonical_depth is not None or app.canonical_branches is not None:
         raise ValueError(
             "many-worlds batching is incompatible with canonical mode "
@@ -231,15 +266,21 @@ def make_batched_packed_exact_fn(app, k: int, *, unroll: int = 1,
     seed, retention = app.seed, app.retention
     pspec = app.packed_spec
 
-    def lane(w, pk):
-        inputs, status, start, _nr, _hl, _ls = unpack_seq(pspec, pk)
+    def lane(w, pk, *src):
+        inputs, status, start, _nr, has_load, load_slot = unpack_seq(
+            pspec, pk
+        )
+        if src:
+            w = _lane_load(w, src[0], has_load, load_slot)
         return resim(
             reg, step, w, inputs, status, start, retention, fps, seed,
             unroll=unroll, fused_checksums=fused_checksums,
         )
 
-    def core(batched_world, packed_b):
-        finals, stacked, checks = jax.vmap(lane)(batched_world, packed_b)
+    def core(batched_world, packed_b, *src_b):
+        finals, stacked, checks = jax.vmap(lane)(
+            batched_world, packed_b, *src_b
+        )
         return finals, stacked, checks.reshape(-1, 2)
 
     if not donate_outputs:
@@ -284,7 +325,9 @@ class BucketedWaveExecutor:
       structure retraces, same shapes hit).
     - ``kind`` — ``exact`` when every lane advances exactly ``bucket`` frames
       (no mask, ~12% faster) or ``padded`` (per-lane ``n_real`` masking) for
-      ragged/partial waves.
+      ragged/partial waves.  A packed wave handed a load source
+      (:meth:`stage_loads`) runs the ``*_load`` kind of either: the same
+      program with the source as a third argument.
 
     All programs run ``unroll=2`` scans with the checksum reduction hoisted
     out of the scan body; both are bit-identical transformations for the
@@ -328,7 +371,8 @@ class BucketedWaveExecutor:
         # so the first call of each (kind, bucket) pays trace+compile — the
         # device-time attribution bench/stats surface (keys "exact_k4", ...)
         self.compile_ms: Dict[str, float] = {}
-        self._timed: Set[Tuple[str, int]] = set()
+        self._timed: Set[Tuple[str, int, str]] = set()
+        self._load_src = None  # stage_loads -> the next run_wave_packed
         self._owner = "wave"
         from .. import telemetry
 
@@ -388,7 +432,8 @@ class BucketedWaveExecutor:
                     self.app, bucket, unroll=self.unroll,
                     fused_checksums=self.fused_checksums, donate_outputs=True,
                 )
-            elif kind == "packed_exact":
+            elif kind in ("packed_exact", "packed_exact_load"):
+                # the *_load kinds: the same program called with a source
                 fn = make_batched_packed_exact_fn(
                     self.app, bucket, unroll=self.unroll,
                     fused_checksums=self.fused_checksums,
@@ -398,7 +443,7 @@ class BucketedWaveExecutor:
                     self.app, bucket, unroll=self.unroll,
                     fused_checksums=self.fused_checksums, donate_outputs=True,
                 )
-            elif kind == "packed_padded":
+            elif kind in ("packed_padded", "packed_padded_load"):
                 fn = make_batched_packed_padded_fn(
                     self.app, bucket, unroll=self.unroll,
                     fused_checksums=self.fused_checksums,
@@ -413,23 +458,26 @@ class BucketedWaveExecutor:
             self._m_compiles.inc()
         return fn
 
-    def _dispatch(self, kind: str, bucket: int, *args):
+    def _dispatch(self, kind: str, bucket: int, *args, variant: str = ""):
         """Call the ``(kind, bucket)`` wave program, timing its FIRST call.
 
         jit returns instantly at build time and compiles at first dispatch,
         so that call's wall time IS the program's trace+compile cost; it
         lands in :attr:`compile_ms`, the flight recorder and (telemetry on)
-        the ``program_compile_ms`` histogram.  Steady-state overhead over a
-        raw ``_get_fn(...)(...)`` call: one extra set lookup."""
-        key = (kind, bucket)
-        if key in self._timed:
-            return self._fns[key](*args)
+        the ``program_compile_ms`` histogram.  ``variant`` names an argument
+        shape the one jitted program is traced for separately (the load
+        source's depth), so each such compile is timed too.  Steady-state
+        overhead over a raw ``_get_fn(...)(...)`` call: one extra set
+        lookup."""
+        timed = (kind, bucket, variant)
+        if timed in self._timed:
+            return self._fns[(kind, bucket)](*args)
         fn = self._get_fn(kind, bucket)
         t0 = time.perf_counter()
         out = fn(*args)
         ms = (time.perf_counter() - t0) * 1e3
-        self._timed.add(key)
-        self.compile_ms[f"{kind}_k{bucket}"] = round(ms, 3)
+        self._timed.add(timed)
+        self.compile_ms[f"{kind}_k{bucket}{variant}"] = round(ms, 3)
         from .. import telemetry
 
         telemetry.flight_recorder().record(
@@ -507,13 +555,24 @@ class BucketedWaveExecutor:
             )
         return bucket, finals, stacked, checks
 
+    def stage_loads(self, src) -> None:
+        """Name the load source of the NEXT :meth:`run_wave_packed` call:
+        every lane whose packed prefix sets ``has_load`` starts from its own
+        row of ``src`` inside the wave program (slot ``load_slot`` of a past
+        stacked wave output ``[M, k', ...]``, or the row of a past resident
+        world ``[M, ...]``) instead of from the resident world — a rollback
+        load with no dispatch of its own.  That call consumes it."""
+        self._load_src = src
+
     def run_wave_packed(self, worlds, packed, ks):
         """Dispatch one wave fed by the packed single-upload staging buffer
         ``packed int8[M, >= bucket + 1, W]`` (per-lane prefix row carries
         that lobby's start frame and ``n_real`` — ops/packing.py); same
         return contract as :meth:`run_wave`.  The whole wave costs ONE
         host->device upload (the resident stacked world never leaves the
-        device)."""
+        device).  A source named by :meth:`stage_loads` routes the wave
+        through the load-carrying programs (never the recycling one)."""
+        src, self._load_src = self._load_src, None
         ks = list(ks)
         k_hot = max(ks)
         if k_hot <= 0:
@@ -528,7 +587,12 @@ class BucketedWaveExecutor:
         self.bucket_hist[bucket] += 1
         self._m_dispatches.inc()
         self._note_uploads(1, pk)
-        if exact:
+        if src is not None:
+            finals, stacked, checks = self._dispatch(
+                "packed_exact_load" if exact else "packed_padded_load",
+                bucket, worlds, pk, src, variant=_src_variant(worlds, src),
+            )
+        elif exact:
             if self.recycle_outputs:
                 key = ("packed_exact_recycle", bucket)
                 prev = self._prev_out.pop(key, None)
@@ -678,7 +742,10 @@ def make_sharded_packed_padded_fn(app, k: int, mesh, *, unroll: int = 1,
     """Packed single-upload variant of :func:`make_sharded_padded_fn`:
     ``fn(batched_world[M], packed int8[M, k + 1, W])`` with both arguments
     sharded over the ``"lobby"`` mesh axis.  Each device unpacks its own
-    block of lanes (prefix bitcast is per-lane, no collectives)."""
+    block of lanes (prefix bitcast is per-lane, no collectives).  The
+    optional in-wave load source ``src_b`` (see
+    :func:`make_batched_packed_padded_fn`) is split by the same lobby spec,
+    so each device reads only its own lanes' source rows."""
     if app.canonical_depth is not None or app.canonical_branches is not None:
         raise ValueError(
             "many-worlds batching is incompatible with canonical mode "
@@ -694,25 +761,29 @@ def make_sharded_packed_padded_fn(app, k: int, mesh, *, unroll: int = 1,
     pspec = app.packed_spec
     spec = P(LOBBY_AXIS)
 
-    def lane(w, pk):
-        inputs, status, start, n_real, _hl, _ls = unpack_seq(pspec, pk)
+    def lane(w, pk, *src):
+        inputs, status, start, n_real, has_load, load_slot = unpack_seq(
+            pspec, pk
+        )
+        if src:
+            w = _lane_load(w, src[0], has_load, load_slot)
         return resim_padded(
             reg, step, w, inputs, status, start, n_real, retention, fps,
             seed, unroll=unroll, fused_checksums=fused_checksums,
         )
 
-    def local(batched_world, packed_b):
-        return jax.vmap(lane)(batched_world, packed_b)
+    def local(batched_world, packed_b, *src_b):
+        return jax.vmap(lane)(batched_world, packed_b, *src_b)
 
     sharded = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(spec, spec),
+        in_specs=spec,  # every argument: its lobby block
         out_specs=(spec, spec, spec),
         check_vma=False,  # no replication to track: lanes are independent
     )
 
-    def body(batched_world, packed_b):
-        finals, stacked, checks = sharded(batched_world, packed_b)
+    def body(batched_world, packed_b, *src_b):
+        finals, stacked, checks = sharded(batched_world, packed_b, *src_b)
         return finals, stacked, checks.reshape(-1, 2)
 
     return jax.jit(body)
@@ -721,7 +792,9 @@ def make_sharded_packed_padded_fn(app, k: int, mesh, *, unroll: int = 1,
 def make_sharded_packed_exact_fn(app, k: int, mesh, *, unroll: int = 1,
                                  fused_checksums: bool = False):
     """Packed single-upload variant of :func:`make_sharded_exact_fn` (no
-    recycling variant, same rationale as the unpacked sharded builder)."""
+    recycling variant, same rationale as the unpacked sharded builder),
+    with the same optional in-wave load source ``src_b`` as
+    :func:`make_sharded_packed_padded_fn`."""
     if app.canonical_depth is not None or app.canonical_branches is not None:
         raise ValueError(
             "many-worlds batching is incompatible with canonical mode "
@@ -737,25 +810,29 @@ def make_sharded_packed_exact_fn(app, k: int, mesh, *, unroll: int = 1,
     pspec = app.packed_spec
     spec = P(LOBBY_AXIS)
 
-    def lane(w, pk):
-        inputs, status, start, _nr, _hl, _ls = unpack_seq(pspec, pk)
+    def lane(w, pk, *src):
+        inputs, status, start, _nr, has_load, load_slot = unpack_seq(
+            pspec, pk
+        )
+        if src:
+            w = _lane_load(w, src[0], has_load, load_slot)
         return resim(
             reg, step, w, inputs, status, start, retention, fps, seed,
             unroll=unroll, fused_checksums=fused_checksums,
         )
 
-    def local(batched_world, packed_b):
-        return jax.vmap(lane)(batched_world, packed_b)
+    def local(batched_world, packed_b, *src_b):
+        return jax.vmap(lane)(batched_world, packed_b, *src_b)
 
     sharded = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(spec, spec),
+        in_specs=spec,  # every argument: its lobby block
         out_specs=(spec, spec, spec),
         check_vma=False,
     )
 
-    def body(batched_world, packed_b):
-        finals, stacked, checks = sharded(batched_world, packed_b)
+    def body(batched_world, packed_b, *src_b):
+        finals, stacked, checks = sharded(batched_world, packed_b, *src_b)
         return finals, stacked, checks.reshape(-1, 2)
 
     return jax.jit(body)
@@ -850,12 +927,12 @@ class ShardedWaveExecutor(BucketedWaveExecutor):
                     self.app, bucket, self.mesh, unroll=self.unroll,
                     fused_checksums=self.fused_checksums,
                 )
-            elif kind == "packed_exact":
+            elif kind in ("packed_exact", "packed_exact_load"):
                 fn = make_sharded_packed_exact_fn(
                     self.app, bucket, self.mesh, unroll=self.unroll,
                     fused_checksums=self.fused_checksums,
                 )
-            elif kind == "packed_padded":
+            elif kind in ("packed_padded", "packed_padded_load"):
                 fn = make_sharded_packed_padded_fn(
                     self.app, bucket, self.mesh, unroll=self.unroll,
                     fused_checksums=self.fused_checksums,
@@ -924,7 +1001,10 @@ class ShardedWaveExecutor(BucketedWaveExecutor):
         """Packed single-upload sharded wave (same contract as the parent's
         :meth:`run_wave_packed`).  Padded lobby lanes get a zeroed prefix
         (``n_real = 0``) so the masked program passes them through — the
-        pad block is built host-side, so the wave still costs ONE upload."""
+        pad block is built host-side, so the wave still costs ONE upload.
+        A source named by :meth:`stage_loads` is split by the same lobby
+        spec as the worlds."""
+        src, self._load_src = self._load_src, None
         ks = list(ks)
         m = len(ks)
         m_pad = self.pad_lobbies(m)
@@ -933,6 +1013,8 @@ class ShardedWaveExecutor(BucketedWaveExecutor):
             from .packing import pack_prefix
 
             worlds = _pad_rows(worlds, pad)
+            if src is not None:
+                src = _pad_rows(src, pad)
             pad_block = np.repeat(packed[-1:], pad, axis=0)
             for r in range(pad):
                 pack_prefix(pad_block[r], 0, 0)
@@ -949,7 +1031,12 @@ class ShardedWaveExecutor(BucketedWaveExecutor):
         self._m_dispatches.inc()
         self._m_sharded_dispatches.inc()
         self._note_uploads(1, pk)
-        if exact:
+        if src is not None:
+            finals, stacked, checks = self._dispatch(
+                "packed_exact_load" if exact else "packed_padded_load",
+                bucket, worlds, pk, src, variant=_src_variant(worlds, src),
+            )
+        elif exact:
             finals, stacked, checks = self._dispatch(
                 "packed_exact", bucket, worlds, pk
             )
@@ -1006,6 +1093,14 @@ class ShardedWaveExecutor(BucketedWaveExecutor):
         out = super().stats()
         out["shard_devices"] = self.n_devices
         return out
+
+
+def _src_variant(worlds, src) -> str:
+    """Compile-accounting name of a load source's shape: ``_src<k'>`` for a
+    stacked ``[M, k', ...]`` wave output, ``_src0`` for a world-shaped
+    ``[M, ...]`` one (each is its own trace of the load-carrying program)."""
+    w, s = jax.tree.leaves(worlds)[0], jax.tree.leaves(src)[0]
+    return f"_src{s.shape[1]}" if s.ndim > w.ndim else "_src0"
 
 
 _pad_rows_jits: Dict[int, object] = {}

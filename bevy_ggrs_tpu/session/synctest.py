@@ -31,6 +31,21 @@ from .requests import (
 )
 
 
+def _landed(entries: List) -> bool:
+    """True when every checksum of a frame can be read without waiting on
+    the device (providers with ``peek()``: their copy has landed); landed
+    values are memoized in place."""
+    for i, e in enumerate(entries):
+        peek = getattr(e, "peek", None)
+        if peek is None:
+            continue
+        v = peek()
+        if v is None:
+            return False
+        entries[i] = v
+    return True
+
+
 class SyncTestSession:
     """Continuous-resimulation determinism oracle (see module docstring)."""
     def __init__(
@@ -110,7 +125,7 @@ class SyncTestSession:
         self._ticks_since_compare += 1
         if self._ticks_since_compare >= self.compare_interval():
             self._ticks_since_compare = 0
-            self._check_mismatches()
+            self._check_mismatches(force=False)
 
         # apply input delay: input staged now takes effect at frame+delay;
         # frames before the first delayed input see the default (zero) input
@@ -159,6 +174,17 @@ class SyncTestSession:
             except Exception:
                 self._compare_interval = 1
         return self._compare_interval
+
+    def stagger_compares(self, phase: int) -> None:
+        """Bring the next deferred comparison ``phase`` ticks forward
+        (modulo the interval), so sessions ticked together can spread
+        their comparisons over the interval instead of paying them all on
+        one tick.  The count only moves forward: no frame waits longer than
+        the interval for its comparison."""
+        interval = self.compare_interval()
+        self._ticks_since_compare = min(
+            self._ticks_since_compare + phase % interval, interval - 1
+        )
 
     def check_now(self) -> None:
         """Force all pending checksum comparisons immediately (raises
@@ -209,14 +235,23 @@ class SyncTestSession:
     def _on_cell_saved(self, frame: int, provider) -> None:
         self._cells.setdefault(frame, []).append(provider)
 
-    def _check_mismatches(self) -> None:
+    def _check_mismatches(self, force: bool = True) -> None:
         # collect any landed async checksum copies first: with the pipelined
         # runner, most providers forced below resolve from the harvested
         # cache instead of blocking on the device
         readback_queue().harvest()
+        # a deferred comparison (not forced, interval > 1) does not wait on
+        # the device: a frame young enough to still take saves whose newest
+        # checksum copy has not landed waits for the next comparison, which
+        # comes before the cell horizon drops it
+        lazy = not force and self.compare_interval() > 1
         mismatched = []
         for frame, entries in self._cells.items():
             if len(entries) < 2:
+                continue
+            if lazy and frame_diff(
+                self.current_frame, frame
+            ) <= self.check_distance + 1 and not _landed(entries):
                 continue
             # only a frame with >=2 checksums is a real comparison — a
             # vacuous sweep must not satisfy the __del__ silent-oracle guard

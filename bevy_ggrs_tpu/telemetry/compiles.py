@@ -51,6 +51,12 @@ def compile_account() -> dict:
                 cache_hit_ratio=_account["cache_hits"] / n if n else None)
 
 
+def programs_built() -> int:
+    """``compile_account()["programs"]`` without building the dict: cheap
+    enough to read every tick."""
+    return _account["programs"]
+
+
 def _on_duration(event: str, duration: float, **_kw) -> None:
     stage = _STAGES.get(event)
     if stage is None:

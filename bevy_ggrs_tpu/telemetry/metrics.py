@@ -388,11 +388,13 @@ class BoundMetric:
             m = self._resolve()
         return m
 
-    def inc(self, n: float = 1) -> None:
-        """Counter/gauge increment by ``n`` (no labels — that's the point)."""
+    def inc(self, n: float = 1, **labels) -> None:
+        """Counter/gauge increment by ``n``; a family with a few fixed label
+        values (``path="in_wave"``) passes them here instead of binding one
+        handle per value."""
         if not self._reg.enabled:
             return
-        self._metric().inc(n)
+        self._metric().inc(n, **labels)
 
     def set(self, v: float) -> None:
         """Gauge set."""

@@ -70,9 +70,11 @@ def test_batched_runner_matches_independent_runners(app_factory):
 
 def test_batched_runner_dispatch_count():
     """The whole point: M lobbies per tick must cost O(waves) dispatches,
-    not O(M) — the warmed-up synctest shape is 3 dispatches/tick (one fused
-    load wave + two run waves), and `device_dispatches` now counts load and
-    store waves too, so a per-lobby fallback would blow the bound."""
+    not O(M) — the warmed-up synctest shape is 2 dispatches/tick (the two
+    run waves; the loads ride the resim wave, see
+    test_in_wave_loads_two_dispatches_per_tick), and `device_dispatches`
+    counts load and store waves too, so a per-lobby fallback would blow the
+    bound."""
     M, TICKS = 8, 12
     app = stress.make_app(64, capacity=64)
     br = BatchedRunner(app, [_session(check_distance=3) for _ in range(M)],
@@ -84,6 +86,68 @@ def test_batched_runner_dispatch_count():
     assert s["device_dispatches"] <= 3 * TICKS, s
     assert s["fallback_loads"] == 0, s
     assert all(f == TICKS for f in s["frames"]), s
+
+
+def test_batched_runner_freezes_the_heap_after_compiling_ticks():
+    """A tick that builds programs collects once and freezes what survives,
+    so later full collections skip set-up's objects; a steady tick freezes
+    nothing, and a process that turned the collector off is left alone."""
+    import gc
+
+    from bevy_ggrs_tpu.telemetry.compiles import programs_built
+
+    gc.unfreeze()
+    try:
+        br = BatchedRunner(stress.make_app(64, capacity=64),
+                           [_session(check_distance=3) for _ in range(2)],
+                           read_inputs=_lobby_inputs_tickless)
+        built = programs_built()
+        br.tick()
+        assert programs_built() > built
+        assert gc.get_freeze_count() > 0
+        for _ in range(10):  # past the ramp's first loads and full waves
+            br.tick()
+        built = programs_built()
+        frozen = gc.get_freeze_count()
+        young = [[] for _ in range(500)]
+        br.tick()
+        assert programs_built() == built
+        assert gc.get_freeze_count() <= frozen, "a steady tick froze the heap"
+        del young
+
+        gc.unfreeze()
+        gc.disable()
+        try:
+            off = BatchedRunner(stress.make_app(48, capacity=48),
+                                [_session(check_distance=3)],
+                                read_inputs=_lobby_inputs_tickless)
+            built = programs_built()
+            off.tick()
+            assert programs_built() > built
+            assert gc.get_freeze_count() == 0
+        finally:
+            gc.enable()
+    finally:
+        gc.unfreeze()
+
+
+def test_batched_runner_staggers_synctest_comparisons():
+    """Lobbies ticked together compare on different ticks of the deferred
+    interval (one tick never pays every lobby's comparison), and the
+    oracle still holds."""
+    M, INTERVAL = 4, 4
+    sessions = [SyncTestSession(num_players=2, input_shape=(),
+                                input_dtype=np.uint8, check_distance=3,
+                                compare_interval=INTERVAL)
+                for _ in range(M)]
+    br = BatchedRunner(stress.make_app(64, capacity=64), sessions,
+                       read_inputs=_lobby_inputs_tickless)
+    per_tick = []
+    for _ in range(12):
+        br.tick()
+        per_tick.append(sum(s._ticks_since_compare == 0 for s in sessions))
+    assert per_tick == [1] * 12, per_tick
+    br.finish()  # raises on any mismatch
 
 
 def test_batched_runner_dispatches_flat_in_lobby_count():
@@ -229,8 +293,10 @@ def test_batched_runner_mixed_source_loads_match_solo():
     br.finish()  # SyncTest oracle across every lobby
 
     s = br.stats()
-    assert s["fallback_loads"] == 0, s  # every load wave was fused
-    assert s["fused_loads"] > 0, s
+    # every load was served without per-lobby fallback, by either fused
+    # path: inside the next run wave or by the separate fused gather
+    assert s["fallback_loads"] == 0, s
+    assert s["fused_loads"] + s["in_wave_loads"] > 0, s
     # the mix really happened: some waves had loads from only PART of the
     # lobbies (lobby 2 skips every other tick)
     assert any(0 < n < len(configs) for n in load_waves), load_waves
@@ -292,13 +358,9 @@ def test_batched_runner_p2p_pair_in_one_batch():
         assert br.lobby_checksum(0) == br.lobby_checksum(1)
 
 
-def test_batched_runner_non_identity_fused_saves_match_solo():
-    """Non-identity strategies flow through the ONE-dispatch vmapped
-    store_state save path (and the fused load applies load_state): quantized
-    bf16 ring storage under batched SyncTest with mixed per-lobby rollback
-    depths must restore exactly and stay bit-identical to solo runners (the
-    per-frame store->load canonicalization absorbs any sub-bf16 float
-    drift, so the comparison is exact)."""
+def _make_quantized_app():
+    """One-player App whose float column rides a non-identity (bf16
+    quantize) snapshot strategy."""
     import dataclasses
 
     import jax.numpy as jnp
@@ -306,30 +368,39 @@ def test_batched_runner_non_identity_fused_saves_match_solo():
     from bevy_ggrs_tpu import App, QuantizeStrategy
     from bevy_ggrs_tpu.snapshot import active_mask, spawn
 
-    def make_qapp():
-        app = App(num_players=1, capacity=4, input_shape=(),
-                  input_dtype=np.uint8)
-        app.rollback_component("x", (), jnp.float32,
-                               strategy=QuantizeStrategy(), checksum=True)
-        app.rollback_component("n", (), jnp.int32, checksum=True)
+    app = App(num_players=1, capacity=4, input_shape=(),
+              input_dtype=np.uint8)
+    app.rollback_component("x", (), jnp.float32,
+                           strategy=QuantizeStrategy(), checksum=True)
+    app.rollback_component("n", (), jnp.int32, checksum=True)
 
-        def step(world, ctx):
-            m = active_mask(world)
-            return dataclasses.replace(world, comps={
-                "x": jnp.where(m & world.has["x"],
-                               world.comps["x"] * 1.001 + 0.01,
-                               world.comps["x"]),
-                "n": jnp.where(m & world.has["n"], world.comps["n"] + 1,
-                               world.comps["n"]),
-            })
+    def step(world, ctx):
+        m = active_mask(world)
+        return dataclasses.replace(world, comps={
+            "x": jnp.where(m & world.has["x"],
+                           world.comps["x"] * 1.001 + 0.01,
+                           world.comps["x"]),
+            "n": jnp.where(m & world.has["n"], world.comps["n"] + 1,
+                           world.comps["n"]),
+        })
 
-        def setup(world):
-            world, _ = spawn(app.reg, world, {"x": 0.3, "n": 0})
-            return world
+    def setup(world):
+        world, _ = spawn(app.reg, world, {"x": 0.3, "n": 0})
+        return world
 
-        app.set_step(step)
-        app.set_setup(setup)
-        return app
+    app.set_step(step)
+    app.set_setup(setup)
+    return app
+
+
+def test_batched_runner_non_identity_fused_saves_match_solo():
+    """Non-identity strategies flow through the ONE-dispatch vmapped
+    store_state save path (and the fused load applies load_state): quantized
+    bf16 ring storage under batched SyncTest with mixed per-lobby rollback
+    depths must restore exactly and stay bit-identical to solo runners (the
+    per-frame store->load canonicalization absorbs any sub-bf16 float
+    drift, so the comparison is exact)."""
+    make_qapp = _make_quantized_app
 
     def make_sess(cd):
         return SyncTestSession(num_players=1, input_shape=(),
@@ -475,3 +546,201 @@ def test_batched_runner_staggered_p2p_rollback_waves():
             assert ca == cb, f"game {g} desynced at frame {f}"
             compared += 1
         assert compared > 0
+
+
+# -- in-wave loads ------------------------------------------------------------
+
+def _separate_loads(br):
+    """Serve every load of ``br`` by the separate fused-load dispatch (no
+    load rides a run wave): the reference the in-wave path must match."""
+    br._defer_loads = lambda *args: {}
+    return br
+
+
+def _assert_trees_equal(a, b):
+    import jax
+
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _assert_rings_equal(ra, rb):
+    """Same stored frames, bit-identical states and checksums."""
+    from bevy_ggrs_tpu.snapshot.checksum import checksum_to_int
+    from bevy_ggrs_tpu.snapshot.lazy import materialize
+
+    assert ra.frames() == rb.frames()
+    for f in ra.frames():
+        (sa, ca), (sb, cb) = ra.peek(f), rb.peek(f)
+        _assert_trees_equal(materialize(sa), materialize(sb))
+        assert checksum_to_int(ca) == checksum_to_int(cb), f
+
+
+@pytest.mark.parametrize("check_distance", [3, 4], ids=["padded", "exact"])
+@pytest.mark.parametrize("app_factory", [
+    lambda: stress.make_app(64, capacity=64),
+    fixed_point.make_app,
+], ids=["stress", "fixed_point"])
+def test_in_wave_loads_bit_identical_to_separate_loads(app_factory,
+                                                       check_distance):
+    """SyncTest loads served inside the resim wave (a per-lane select keyed
+    by the packed prefix) leave every world leaf, every live checksum and
+    every ring entry bit-identical to loads served by the separate fused
+    dispatch — through the padded (check distance 3, bucket 4) and the
+    exact (check distance 4) load-carrying programs."""
+    M, TICKS = 4, 14
+    tick = [0]
+
+    def read_inputs(lobby, handles):
+        return _lobby_inputs(lobby, tick[0], handles)
+
+    in_wave, separate = [
+        BatchedRunner(app_factory(),
+                      [_session(check_distance) for _ in range(M)],
+                      read_inputs=read_inputs)
+        for _ in range(2)
+    ]
+    _separate_loads(separate)
+    for _ in range(TICKS):
+        in_wave.tick()
+        separate.tick()
+        tick[0] += 1
+        _assert_trees_equal(in_wave.worlds, separate.worlds)
+        for b in range(M):
+            assert in_wave.lobby_checksum(b) == separate.lobby_checksum(b)
+            _assert_rings_equal(in_wave.rings[b], separate.rings[b])
+    in_wave.finish()
+    separate.finish()
+    s, r = in_wave.stats(), separate.stats()
+    assert s["in_wave_loads"] == s["rollbacks"] > 0, s
+    assert s["fused_loads"] == 0 and s["fallback_loads"] == 0, s
+    assert r["in_wave_loads"] == 0 and r["fused_loads"] > 0, r
+
+
+def test_in_wave_loads_two_dispatches_per_tick():
+    """Warmed-up SyncTest: the 1-frame wave and the resim wave that carries
+    every lobby's load — at most 2 device dispatches a tick, whatever M."""
+    M, WARM, TICKS = 8, 5, 10
+    app = stress.make_app(64, capacity=64)
+    br = BatchedRunner(app, [_session(check_distance=3) for _ in range(M)],
+                       read_inputs=_lobby_inputs_tickless)
+    for _ in range(WARM):
+        br.tick()
+    d0, l0 = br.device_dispatches, br.in_wave_loads
+    for _ in range(TICKS):
+        br.tick()
+    br.finish()
+    s = br.stats()
+    assert br.device_dispatches - d0 <= 2 * TICKS, s
+    assert br.in_wave_loads - l0 == M * TICKS, s
+    assert s["fused_loads"] == 0 and s["fallback_loads"] == 0, s
+
+
+def _scripted_pair(scripts, separate=False):
+    """Two scripted box_game lobbies, one tick per script entry."""
+    from bevy_ggrs_tpu.models import box_game
+    from tests.test_speculative_runner import ScriptedSession
+
+    sessions = [ScriptedSession([]) for _ in scripts]
+    for sess, make in zip(sessions, scripts):
+        sess.script = make(sess)
+    br = BatchedRunner(box_game.make_app(num_players=2), sessions)
+    if separate:
+        _separate_loads(br)
+    for _ in range(len(sessions[0].script)):
+        br.tick()
+    return br
+
+
+def _save(sess, f):
+    from bevy_ggrs_tpu.session.requests import SaveCell, SaveRequest
+
+    return SaveRequest(f, SaveCell(sess, f))
+
+
+def test_in_wave_load_then_save_saves_the_loaded_state():
+    """A Save before the first Advance of a run that follows a deferred
+    load (a P2P rollback to the live frame) must store the LOADED state: the
+    lane's resident row is not loaded until the wave program runs."""
+    from bevy_ggrs_tpu.models import box_game
+    from bevy_ggrs_tpu.session.requests import LoadRequest
+    from tests.test_speculative_runner import adv
+
+    a = [box_game.keys_to_input(right=True), 0]
+    b = [box_game.keys_to_input(up=True), 0]
+
+    def script(sess):
+        return [
+            [_save(sess, 0), adv(a), _save(sess, 1), adv(a)],
+            [LoadRequest(0), _save(sess, 0), adv(b), _save(sess, 1), adv(b)],
+        ]
+
+    in_wave = _scripted_pair([script, script])
+    separate = _scripted_pair([script, script], separate=True)
+    assert in_wave.stats()["in_wave_loads"] == 2
+    assert separate.stats()["in_wave_loads"] == 0
+    _assert_trees_equal(in_wave.worlds, separate.worlds)
+    for b_ in range(2):
+        _assert_rings_equal(in_wave.rings[b_], separate.rings[b_])
+        assert in_wave.lobby_checksum(b_) == separate.lobby_checksum(b_)
+
+
+def test_in_wave_loads_from_two_buffers_use_the_fused_load():
+    """Loads of one wave that read two past buffers (lobby 0 a pre-wave
+    resident world, lobby 1 a stacked wave output) are all served by the
+    separate fused load, with the same result as forcing it."""
+    from bevy_ggrs_tpu.session.requests import LoadRequest
+    from tests.test_speculative_runner import adv
+
+    x = [3, 0]
+
+    def lobby0(sess):
+        return [[_save(sess, 0), adv(x), _save(sess, 1), adv(x)],
+                [LoadRequest(0), adv(x), adv(x)]]
+
+    def lobby1(sess):
+        return [[_save(sess, 0), adv(x), _save(sess, 1), adv(x)],
+                [LoadRequest(1), adv(x)]]
+
+    br = _scripted_pair([lobby0, lobby1])
+    ref = _scripted_pair([lobby0, lobby1], separate=True)
+    s = br.stats()
+    assert s["in_wave_loads"] == 0 and s["fused_loads"] == 1, s
+    _assert_trees_equal(br.worlds, ref.worlds)
+
+
+def test_in_wave_loads_off_for_non_identity_strategy():
+    """A non-identity strategy's loads need its ``load_state`` transform,
+    which only the separate fused load applies: nothing is deferred."""
+    sessions = [
+        SyncTestSession(num_players=1, input_shape=(), input_dtype=np.uint8,
+                        check_distance=3, compare_interval=1)
+        for _ in range(2)
+    ]
+    br = BatchedRunner(
+        _make_quantized_app(), sessions,
+        read_inputs=lambda lobby, handles: {h: np.uint8(0) for h in handles},
+    )
+    for _ in range(8):
+        br.tick()
+    br.finish()
+    s = br.stats()
+    assert s["rollbacks"] > 0 and s["in_wave_loads"] == 0, s
+    assert s["fused_loads"] > 0, s
+
+
+def test_in_wave_loads_off_with_speculation():
+    """With speculation on, a missed lookup's load goes through the
+    separate fused load (speculation hits consume the run op the load would
+    ride); the speculation-less runner serves the same load in-wave, with
+    the same result."""
+    from tests.test_batched_speculation import _run_pair, _spec
+
+    corrected = np.uint8(9)  # not among the hedged values: a miss
+    spec = _run_pair(_spec([0, 1, 2, 3]), corrected).stats()
+    plain = _run_pair(None, corrected)
+    assert spec["speculation"]["misses"] >= 1
+    assert spec["in_wave_loads"] == 0 and spec["fused_loads"] == 1, spec
+    assert plain.stats()["in_wave_loads"] == 1

@@ -249,3 +249,89 @@ def test_multichip_metrics_parse():
     )
     metrics = multichip_bench.parse_metrics(tail)
     assert [m["program"] for m in metrics] == ["canonical", "sharded_wave"]
+
+
+# -- in-wave loads ----------------------------------------------------------
+
+def test_sharded_in_wave_loads_match_unsharded_separate_loads(eight_devices):
+    """Lobby-sharded runner with SyncTest loads served inside the resim
+    wave (each device selects its own lanes' source rows) against the
+    unsharded runner with every load on the separate fused dispatch: every
+    live world leaf, live checksum and ring entry bit-identical.  M=6 on 8
+    devices keeps two pad lanes in the sharded source buffers."""
+    import jax
+
+    from bevy_ggrs_tpu.snapshot.lazy import materialize
+    from bevy_ggrs_tpu.snapshot.checksum import checksum_to_int
+
+    M, TICKS = 6, 12
+    tick = [0]
+
+    def read_inputs(lobby, handles):
+        return _lobby_inputs(lobby, tick[0], handles)
+
+    def runner(mesh):
+        sessions = [
+            SyncTestSession(num_players=2, input_shape=(),
+                            input_dtype=np.uint8, check_distance=3,
+                            compare_interval=1)
+            for _ in range(M)
+        ]
+        return BatchedRunner(fixed_point.make_app(), sessions,
+                             read_inputs=read_inputs, mesh=mesh)
+
+    sh = runner(make_lobby_mesh(8))
+    ref = runner(None)
+    ref._defer_loads = lambda *args: {}  # every load on the fused dispatch
+    for _ in range(TICKS):
+        sh.tick()
+        ref.tick()
+        tick[0] += 1
+        live = jax.tree.map(lambda a: a[:M], sh.worlds)
+        assert _tree_equal(live, ref.worlds)
+        for b in range(M):
+            assert sh.lobby_checksum(b) == ref.lobby_checksum(b)
+            assert sh.rings[b].frames() == ref.rings[b].frames()
+            for f in ref.rings[b].frames():
+                (s_sh, c_sh), (s_ref, c_ref) = (
+                    sh.rings[b].peek(f), ref.rings[b].peek(f)
+                )
+                assert _tree_equal(materialize(s_sh), materialize(s_ref))
+                assert checksum_to_int(c_sh) == checksum_to_int(c_ref)
+    sh.finish()
+    ref.finish()
+    s = sh.stats()
+    assert s["in_wave_loads"] == s["rollbacks"] > 0, s
+    assert s["fused_loads"] == 0, s
+    assert ref.stats()["in_wave_loads"] == 0
+
+
+@pytest.mark.parametrize("kind", ["packed_padded_load", "packed_exact_load"])
+def test_sharded_load_wave_has_no_collectives(eight_devices, kind):
+    """The load-carrying sharded wave splits its source buffer by the same
+    lobby spec as the worlds: each lane selects its own row, so the
+    compiled program holds no collective (the separate fused load gathers
+    with replicated indices from lobby-sharded buffers instead)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from bevy_ggrs_tpu.parallel.mesh import LOBBY_AXIS
+
+    app = stress.make_app(64, capacity=64)
+    mesh = make_lobby_mesh(8)
+    m, bucket, src_k = 8, 4, 4
+    ex = ShardedWaveExecutor(app, bucket, mesh)
+    lobby = NamedSharding(mesh, P(LOBBY_AXIS))
+    w = app.init_state()
+    worlds = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((m, *a.shape), a.dtype,
+                                       sharding=lobby), w)
+    src = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((m, src_k, *a.shape), a.dtype,
+                                       sharding=lobby), w)
+    pk = jax.ShapeDtypeStruct((m, bucket + 1, app.packed_spec.width),
+                              jnp.int8, sharding=lobby)
+    hlo = ex._get_fn(kind, bucket).lower(worlds, pk, src).compile().as_text()
+    for op in ("all-reduce", "all-gather", "collective-permute", "all-to-all"):
+        assert op not in hlo, f"unexpected {op} in the sharded load wave"

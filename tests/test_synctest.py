@@ -11,7 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bevy_ggrs_tpu import App, GgrsRunner, SyncTestSession
+from bevy_ggrs_tpu import (
+    App, GgrsRunner, MismatchedChecksumError, SyncTestSession,
+)
 from bevy_ggrs_tpu.models import box_game
 from bevy_ggrs_tpu.snapshot import active_count, active_mask, despawn_where, spawn
 
@@ -244,6 +246,97 @@ def test_deferred_compare_batches_and_still_detects():
     assert mismatches, "deferred comparison never fired"
     assert any(f >= bad_frame - session.check_distance
                for f in mismatches[0].mismatched_frames)
+
+
+def test_stagger_compares_moves_the_next_comparison_forward_only():
+    def ticks_that_compare(session, n):
+        out = []
+        for t in range(1, n + 1):
+            session.add_local_input(0, np.uint8(0))
+            session.advance_frame()
+            if session._ticks_since_compare == 0:
+                out.append(t)
+        return out
+
+    def fresh():
+        return SyncTestSession(num_players=1, input_shape=(),
+                               input_dtype=np.uint8, check_distance=2,
+                               compare_interval=8)
+
+    s = fresh()
+    s.stagger_compares(3)
+    assert ticks_that_compare(s, 20) == [5, 13]
+    s = fresh()
+    s.stagger_compares(8 + 3)  # modulo the interval
+    assert ticks_that_compare(s, 6) == [5]
+    s = fresh()
+    assert ticks_that_compare(s, 6) == []
+    s.stagger_compares(5)  # past the count: the next tick, never later
+    assert ticks_that_compare(s, 9) == [1, 9]
+
+
+def test_staggered_deferred_compare_still_detects():
+    runner, session, mismatches = _deferred_runner(interval=8)
+    session.stagger_compares(5)
+    for _ in range(10):
+        runner.tick()
+    assert mismatches == []
+    _inject_divergence(runner)
+    bad_frame = runner.frame
+    for i in range(session.compare_interval() + session.check_distance + 2):
+        runner.tick()
+        if mismatches:
+            break
+    assert mismatches, "deferred comparison never fired"
+    assert any(f >= bad_frame - session.check_distance
+               for f in mismatches[0].mismatched_frames)
+
+
+class _LateChecksum:
+    """A checksum provider whose device copy lands when ``ready`` is set."""
+
+    def __init__(self, value):
+        self.value, self.ready, self.forced = value, False, 0
+
+    def peek(self):
+        return self.value if self.ready else None
+
+    def __call__(self):
+        self.forced += 1
+        return self.value
+
+
+def test_deferred_compare_leaves_unlanded_young_frames_for_the_next():
+    s = SyncTestSession(num_players=1, input_shape=(), input_dtype=np.uint8,
+                        check_distance=2, compare_interval=4)
+    s.current_frame = 10
+    young = [_LateChecksum(7), _LateChecksum(8)]  # age 1: disagree
+    old = [_LateChecksum(5), _LateChecksum(5)]  # age 4 > d + 1: complete
+    for p in young:
+        s._on_cell_saved(9, p)
+    for p in old:
+        s._on_cell_saved(6, p)
+    s._check_mismatches(force=False)  # deferred: no wait, no raise
+    assert [p.forced for p in young] == [0, 0]
+    assert [p.forced for p in old] == [1, 1]
+    assert s.pending_comparisons() == 1
+    for p in young:
+        p.ready = True
+    with pytest.raises(MismatchedChecksumError) as e:
+        s._check_mismatches(force=False)
+    assert e.value.mismatched_frames == [9]
+
+
+def test_forced_compare_waits_for_every_frame():
+    s = SyncTestSession(num_players=1, input_shape=(), input_dtype=np.uint8,
+                        check_distance=2, compare_interval=4)
+    s.current_frame = 10
+    young = [_LateChecksum(7), _LateChecksum(8)]
+    for p in young:
+        s._on_cell_saved(9, p)
+    with pytest.raises(MismatchedChecksumError):
+        s.check_now()
+    assert [p.forced for p in young] == [1, 1]
 
 
 def test_check_now_forces_pending_comparisons():
